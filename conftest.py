@@ -4,11 +4,12 @@ When the real ``hypothesis`` package is unavailable (hermetic containers
 where ``pip install`` is not an option), install a deterministic,
 minimal stand-in into ``sys.modules`` before test collection so the
 property-test modules still collect and run.  CI installs the real
-package via ``pip install -e .[test]``, in which case this shim is
-completely inert.
+package via ``pip install -e .[test]``; with it, this file only loads a
+profile that derandomizes every property test and keeps no example
+database, so each run draws the same examples and writes nothing.
 
 The stub covers exactly the API surface the test-suite uses — ``given``,
-``settings``, ``assume`` and the ``integers`` / ``floats`` / ``booleans``
+``settings``, ``example``, ``assume`` and the ``integers`` / ``floats`` / ``booleans``
 / ``sampled_from`` / ``lists`` / ``tuples`` / ``just`` / ``one_of`` /
 ``permutations`` / ``sets`` / ``data`` / ``composite`` strategies —
 drawing
@@ -153,6 +154,14 @@ def _build_hypothesis_stub() -> types.ModuleType:
         all = staticmethod(lambda: [])
         too_slow = data_too_large = filter_too_much = None
 
+    def example(**kwargs):
+        """Pin one explicit example; ``given`` runs pinned examples
+        before drawing any."""
+        def decorate(fn):
+            fn._stub_examples = [kwargs] + getattr(fn, "_stub_examples", [])
+            return fn
+        return decorate
+
     def given(*given_args, **given_kwargs):
         def decorate(fn):
             sig = inspect.signature(fn)
@@ -169,6 +178,8 @@ def _build_hypothesis_stub() -> types.ModuleType:
                 cfg = (getattr(wrapper, "_stub_settings", None)
                        or getattr(fn, "_stub_settings", None))
                 n_examples = cfg.max_examples if cfg else 20
+                for pinned in getattr(fn, "_stub_examples", []):
+                    fn(*args, **kwargs, **pinned)
                 rnd = random.Random(f"{fn.__module__}.{fn.__qualname__}")
                 ran = 0
                 for _ in range(n_examples * 5):
@@ -188,6 +199,7 @@ def _build_hypothesis_stub() -> types.ModuleType:
         return decorate
 
     mod.given = given
+    mod.example = example
     mod.settings = settings
     mod.assume = assume
     mod.HealthCheck = HealthCheck
@@ -196,8 +208,12 @@ def _build_hypothesis_stub() -> types.ModuleType:
 
 
 try:
-    import hypothesis  # noqa: F401
+    import hypothesis
 except ImportError:
     _stub = _build_hypothesis_stub()
     sys.modules["hypothesis"] = _stub
     sys.modules["hypothesis.strategies"] = _stub.strategies
+else:
+    hypothesis.settings.register_profile(
+        "repro", derandomize=True, database=None, deadline=None)
+    hypothesis.settings.load_profile("repro")

@@ -216,13 +216,18 @@ class DeviceTransferBackend(TransferBackend):
         self.device = device or jax.devices()[0]
 
     def _sharding(self, memory_kind: Optional[str]):
+        """Target sharding for ``memory_kind``.  A kind the device does
+        not have raises: landing the payload in some other memory would
+        move the bytes but not to the tier the caller asked for."""
         s = jax.sharding.SingleDeviceSharding(self.device)
-        if memory_kind is not None:
-            try:
-                s = s.with_memory_kind(memory_kind)
-            except Exception:  # backend without memory-kind support
-                pass
-        return s
+        if memory_kind is None:
+            return s
+        kinds = {m.kind for m in self.device.addressable_memories()}
+        if memory_kind not in kinds:
+            raise ValueError(
+                f"{self.device} has no memory kind {memory_kind!r} "
+                f"(it has {sorted(kinds)})")
+        return s.with_memory_kind(memory_kind)
 
     def start(self, req: Request) -> None:
         src, memory_kind = req.payload

@@ -35,7 +35,8 @@ from repro.optim.adamw import adamw_init, adamw_update
 __all__ = [
     "make_train_step", "make_prefill_step", "make_serve_step",
     "make_mixed_step", "abstract_params", "abstract_opt_state",
-    "train_inputs", "decode_inputs", "paged_cache_specs",
+    "init_params_sharded", "train_inputs", "decode_inputs",
+    "paged_cache_specs",
 ]
 
 
@@ -44,6 +45,16 @@ __all__ = [
 def abstract_params(cfg: ModelConfig):
     return jax.eval_shape(lambda k: model_mod.init_params(cfg, k),
                           jax.random.PRNGKey(0))
+
+
+def init_params_sharded(cfg: ModelConfig, mesh, key):
+    """Random parameters created straight into their :func:`param_specs`
+    shardings on ``mesh``: each device generates only its own shards,
+    so a model larger than one device's memory never lands on one."""
+    pspecs = param_specs(mesh, abstract_params(cfg))
+    init = jax.jit(lambda k: model_mod.init_params(cfg, k),
+                   out_shardings=_named(mesh, pspecs))
+    return init(key)
 
 
 def abstract_opt_state(cfg: ModelConfig):

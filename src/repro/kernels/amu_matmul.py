@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.spm import plan_matmul_blocks
+from repro.kernels.decode_attention import resolve_interpret
 
 __all__ = ["amu_matmul"]
 
@@ -98,7 +99,7 @@ def amu_matmul(
     bm: Optional[int] = None,
     bk: Optional[int] = None,
     bn: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     M, K = x.shape
     K2, N = w.shape
@@ -118,8 +119,8 @@ def amu_matmul(
         kernel,
         grid=(M // bm, N // bn),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # x stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),    # w stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),    # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),    # w stays in HBM
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
@@ -130,5 +131,5 @@ def amu_matmul(
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)

@@ -28,7 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention", "paged_decode_attention",
-           "paged_verify_attention", "default_interpret"]
+           "paged_verify_attention", "default_interpret",
+           "resolve_interpret"]
 
 NEG_INF = -1e30
 _LANE = 128
@@ -44,6 +45,12 @@ def default_interpret() -> bool:
     compiled-kernel backend is available.
     """
     return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel entry point's ``interpret`` argument: ``None`` means
+    backend auto-detection (:func:`default_interpret`)."""
+    return default_interpret() if interpret is None else interpret
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
@@ -91,8 +98,7 @@ def decode_attention(
     bkv: int = 256,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    if interpret is None:                  # auto: compiled on TPU only
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     B, H, D = q.shape
     _, Skv, Hkv, _ = k.shape
     G = H // Hkv
@@ -127,58 +133,172 @@ def decode_attention(
 
 
 # ---------------------------------------------------------------------------
-# paged variant: gather-by-page-table (repro.paging pool layout)
+# paged variants: gather-by-page-table (repro.paging pool layout)
 # ---------------------------------------------------------------------------
+#
+# Blocking: Mosaic needs the last two dims of every block divisible by
+# (8, 128) or equal to the array's own.  The pool is (N, page, Hkv, D),
+# so each grid step stages one WHOLE frame (1, page, Hkv, D) — every KV
+# head of one page — and the kernel loops over the heads inside.  A
+# per-head (1, page, 1, D) block would cut one head out of the
+# second-to-last axis, which the TPU compiler refuses.  A quantized
+# pool's (N, Hkv) f32 scales are read through their (N, 1, Hkv) view,
+# one frame's (1, 1, Hkv) row per step: again the array's own last two
+# dims.
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         scale: float, page: int, G: int,
-                         quant: bool = False):
-    # quantized pools thread two extra scale operands between the pool
-    # refs and the output ref; the dequant multiply fuses into the
-    # existing f32 upcast of the VMEM-staged frame
+def frame_slot(j, valid_len, page: int):
+    """Page slot grid step ``j`` stages: steps past the sequence's last
+    live page re-point at that page, so the pipeline sees an unchanged
+    block index and issues no DMA for them (their compute is skipped by
+    the length mask anyway)."""
+    return jnp.minimum(j, jnp.maximum(valid_len - 1, 0) // page)
+
+
+def scale_rows(scales: jnp.ndarray) -> jnp.ndarray:
+    """(N, Hkv) frame scales -> the (N, 1, Hkv) view the kernels block."""
+    return scales.reshape(scales.shape[0], 1, scales.shape[1])
+
+
+def head_kv(k_ref, v_ref, ks_ref, vs_ref, h: int):
+    """KV head ``h`` of the staged frame as f32 (page, D) — dequantized
+    when the pool is quantized (the multiply fuses into the upcast)."""
+    k = k_ref[0, :, h, :].astype(jnp.float32)
+    v = v_ref[0, :, h, :].astype(jnp.float32)
+    if ks_ref is not None:
+        k = k * ks_ref[0, :, h:h + 1]
+        v = v * vs_ref[0, :, h:h + 1]
+    return k, v
+
+
+def init_carry(acc, m_s, l_s) -> None:
+    acc[...] = jnp.zeros_like(acc)
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+
+
+def online_softmax_step(q, k, v, mask, acc, m_s, l_s, i: int) -> None:
+    """Fold one page into carry row-group ``i``: ``q`` (R, D) f32
+    pre-scaled, ``k``/``v`` (page, D) f32, ``mask`` (R, page); ``acc``
+    (n, R, D) and ``m_s``/``l_s`` (n, R, lanes) are VMEM scratch."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (R, page)
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_s[i, :, :1]
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_s[i, :, :1] = l_s[i, :, :1] * corr + p.sum(-1, keepdims=True)
+    m_s[i, :, :1] = m_new
+    acc[i] = acc[i] * corr + jax.lax.dot(p, v)
+
+
+def finish_carry(o_ref, acc, l_s, i: int) -> None:
+    o_ref[0, i] = (acc[i] / jnp.maximum(l_s[i, :, :1], 1e-30)) \
+        .astype(o_ref.dtype)
+
+
+def _paged_multi_query_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                              scale: float, page: int, Hkv: int, G: int,
+                              S: int, quant: bool):
+    """Shared body of the decode (S = 1) and verify (S = K + 1) kernels.
+
+    Grid ``(B, pages_per_seq)``: one sequence's frames stream through
+    VMEM; q is one (Hkv, S*G, D) block per sequence, the rows of KV head
+    ``h`` being its S verify rows × G grouped query heads."""
     if quant:
         ks_ref, vs_ref, o_ref, acc, m_s, l_s = rest
     else:
         ks_ref = vs_ref = None
         o_ref, acc, m_s, l_s = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    n_pg = pl.num_programs(2)
+    j = pl.program_id(1)
+    n_pg = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
+        init_carry(acc, m_s, l_s)
 
-    valid_len = len_ref[b]
+    # per-row valid lengths: verify row s sees s more KV positions than
+    # row 0 (its own token plus every draft before it).  S is small and
+    # static, so the SMEM reads unroll at trace time.
+    vals = [len_ref[b, s] for s in range(S)]
+    valid_max = vals[0]
+    for vl in vals[1:]:
+        valid_max = jnp.maximum(valid_max, vl)
     first_kv = j * page
 
-    @pl.when(first_kv < valid_len)
+    @pl.when(first_kv < valid_max)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        if quant:
-            k = k * ks_ref[0, 0]           # per-(frame, head) f32 scale
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, page)
-        kv_pos = first_kv + jax.lax.broadcasted_iota(jnp.int32, (G, page), 1)
-        s = jnp.where(kv_pos < valid_len, s, NEG_INF)
-        m_prev = m_s[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[:, :1] = l_s[:, :1] * corr + p.sum(-1, keepdims=True)
-        m_s[:, :1] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        if quant:
-            v = v * vs_ref[0, 0]
-        acc[...] = acc[...] * corr + jax.lax.dot(p, v)
+        kv_pos = first_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (S * G, page), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (S * G, page), 0) // G
+        valid_rows = jnp.full((S * G, page), vals[0], jnp.int32)
+        for s in range(1, S):
+            valid_rows = jnp.where(row == s, vals[s], valid_rows)
+        mask = kv_pos < valid_rows
+        for h in range(Hkv):
+            q = q_ref[0, h].astype(jnp.float32) * scale        # (S*G, D)
+            k, v = head_kv(k_ref, v_ref, ks_ref, vs_ref, h)
+            online_softmax_step(q, k, v, mask, acc, m_s, l_s, h)
 
     @pl.when(j == n_pg - 1)
     def _():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l_s[:, :1], 1e-30)) \
-            .astype(o_ref.dtype)
+        for h in range(Hkv):
+            finish_carry(o_ref, acc, l_s, h)
+
+
+def _paged_multi_query(qg, k_pages, v_pages, page_table, lengths, k_scales,
+                       v_scales, *, interpret: bool):
+    """pallas_call over ``qg`` (B, Hkv, S*G, D) with ``lengths`` (B, S)."""
+    B, Hkv, R, D = qg.shape
+    N, page, _, _ = k_pages.shape
+    S = lengths.shape[1]
+    G = R // S
+    pages_per_seq = page_table.shape[1]
+    quant = k_scales is not None
+
+    def frame(b, j, pt, ln):
+        longest = ln[b, 0]
+        for s in range(1, S):
+            longest = jnp.maximum(longest, ln[b, s])
+        return pt[b, frame_slot(j, longest, page)]
+
+    in_specs = [
+        pl.BlockSpec((1, Hkv, R, D), lambda b, j, pt, ln: (b, 0, 0, 0)),
+        pl.BlockSpec((1, page, Hkv, D),
+                     lambda b, j, pt, ln: (frame(b, j, pt, ln), 0, 0, 0)),
+        pl.BlockSpec((1, page, Hkv, D),
+                     lambda b, j, pt, ln: (frame(b, j, pt, ln), 0, 0, 0)),
+    ]
+    operands = [qg, k_pages, v_pages]
+    if quant:
+        # one f32 scale per (frame, head): the same page-table
+        # dereference the frame DMA uses picks the scale row
+        spec = pl.BlockSpec((1, 1, Hkv),
+                            lambda b, j, pt, ln: (frame(b, j, pt, ln), 0, 0))
+        in_specs += [spec, spec]
+        operands += [scale_rows(k_scales), scale_rows(v_scales)]
+    kernel = functools.partial(_paged_multi_query_kernel,
+                               scale=1.0 / math.sqrt(D), page=page, Hkv=Hkv,
+                               G=G, S=S, quant=quant)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, pages_per_seq),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, Hkv, R, D),
+                               lambda b, j, pt, ln: (b, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, R, D), jnp.float32),
+            pltpu.VMEM((Hkv, R, _LANE), jnp.float32),
+            pltpu.VMEM((Hkv, R, _LANE), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), qg.dtype),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -203,122 +323,20 @@ def paged_decode_attention(
     through VMEM, so the gather rides the compiler-pipelined DMA for
     free (the AMU gather pattern at page granularity — same scheme as
     ``moe_gather.gather_blocks``).  Entries past a sequence's last page
-    must still hold in-bounds frame ids (0 is fine): their tiles are
-    skipped by the per-sequence ``lengths`` mask but may be prefetched.
+    must still hold in-bounds frame ids (0 is fine); those steps re-use
+    the last live frame and are skipped by the per-sequence mask.
 
     Per-sequence ``lengths`` (unlike the dense kernel's static
     ``valid_len``) make one call serve the engine's mixed-depth batch.
     """
-    if interpret is None:                  # auto: compiled on TPU only
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     B, H, D = q.shape
-    N, page, Hkv, _ = k_pages.shape
-    G = H // Hkv
-    pages_per_seq = page_table.shape[1]
-
-    qg = q.reshape(B, Hkv, G, D)
-    quant = k_scales is not None
-
-    kernel = functools.partial(_paged_decode_kernel,
-                               scale=1.0 / math.sqrt(D), page=page, G=G,
-                               quant=quant)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D),
-                     lambda b, h, j, pt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-    ]
-    operands = [qg, k_pages, v_pages]
-    if quant:
-        # one f32 scale per (frame, head): the same page-table
-        # dereference the frame DMA uses picks the scale block
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, h, j, pt, ln: (pt[b, j], h)),
-            pl.BlockSpec((1, 1), lambda b, h, j, pt, ln: (pt[b, j], h)),
-        ]
-        operands += [k_scales, v_scales]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, pages_per_seq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, j, pt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, _LANE), jnp.float32),
-            pltpu.VMEM((G, _LANE), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    Hkv = k_pages.shape[2]
+    qg = q.reshape(B, Hkv, H // Hkv, D)
+    out = _paged_multi_query(qg, k_pages, v_pages, page_table,
+                             lengths.reshape(B, 1), k_scales, v_scales,
+                             interpret=interpret)
     return out.reshape(B, H, D)
-
-
-# ---------------------------------------------------------------------------
-# multi-query paged variant: S query rows per sequence (speculative verify)
-# ---------------------------------------------------------------------------
-
-
-def _paged_verify_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         scale: float, page: int, G: int, S: int,
-                         quant: bool = False):
-    if quant:
-        ks_ref, vs_ref, o_ref, acc, m_s, l_s = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc, m_s, l_s = rest
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    n_pg = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-
-    # per-row valid lengths: verify row s sees s more KV positions than
-    # row 0 (its own token plus every draft before it).  S is small and
-    # static, so the SMEM reads unroll at trace time.
-    vals = [len_ref[b, s] for s in range(S)]
-    valid_max = vals[0]
-    for vl in vals[1:]:
-        valid_max = jnp.maximum(valid_max, vl)
-    valid_rows = jnp.broadcast_to(jnp.stack(vals)[:, None],
-                                  (S, G)).reshape(S * G, 1)
-    first_kv = j * page
-
-    @pl.when(first_kv < valid_max)
-    def _():
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (S*G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        if quant:
-            k = k * ks_ref[0, 0]           # per-(frame, head) f32 scale
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (S*G, page)
-        kv_pos = first_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (S * G, page), 1)
-        s = jnp.where(kv_pos < valid_rows, s, NEG_INF)
-        m_prev = m_s[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[:, :1] = l_s[:, :1] * corr + p.sum(-1, keepdims=True)
-        m_s[:, :1] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        if quant:
-            v = v * vs_ref[0, 0]
-        acc[...] = acc[...] * corr + jax.lax.dot(p, v)
-
-    @pl.when(j == n_pg - 1)
-    def _():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l_s[:, :1], 1e-30)) \
-            .astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -344,59 +362,19 @@ def paged_verify_attention(
     tokens (the paper's amortise-per-access-overhead lever).
 
     ``lengths[b, s]`` masks row ``s`` independently (row s's causal view
-    includes the draft rows before it).  A fully-masked row
-    (``lengths[b, s] == 0``) returns zeros here; the XLA reference path
-    returns the uniform value average instead — callers only consume
-    rows with ``lengths >= 1``, where the two agree.
+    includes the draft rows before it).  A fully-masked row (``lengths[b, s] == 0``) returns zeros here; the
+    XLA reference path returns the uniform value average instead —
+    callers only consume rows with ``lengths >= 1``, where the two agree.
     """
-    if interpret is None:                  # auto: compiled on TPU only
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     B, S, H, D = q.shape
-    N, page, Hkv, _ = k_pages.shape
+    Hkv = k_pages.shape[2]
     G = H // Hkv
-    pages_per_seq = page_table.shape[1]
-
     # (B, S, Hkv, G, D) -> (B, Hkv, S*G, D): rows of one KV head stay
     # contiguous so the kernel's (S*G, page) tile covers all verify rows
     qg = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 1, 3, 4) \
          .reshape(B, Hkv, S * G, D)
-
-    quant = k_scales is not None
-    kernel = functools.partial(_paged_verify_kernel,
-                               scale=1.0 / math.sqrt(D), page=page,
-                               G=G, S=S, quant=quant)
-    in_specs = [
-        pl.BlockSpec((1, 1, S * G, D),
-                     lambda b, h, j, pt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-    ]
-    operands = [qg, k_pages, v_pages]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, h, j, pt, ln: (pt[b, j], h)),
-            pl.BlockSpec((1, 1), lambda b, h, j, pt, ln: (pt[b, j], h)),
-        ]
-        operands += [k_scales, v_scales]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, pages_per_seq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, S * G, D),
-                               lambda b, h, j, pt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((S * G, D), jnp.float32),
-            pltpu.VMEM((S * G, _LANE), jnp.float32),
-            pltpu.VMEM((S * G, _LANE), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, S * G, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    out = _paged_multi_query(qg, k_pages, v_pages, page_table, lengths,
+                             k_scales, v_scales, interpret=interpret)
     return out.reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4) \
               .reshape(B, S, H, D)

@@ -25,6 +25,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import (finish_carry, frame_slot,
+                                            head_kv, init_carry,
+                                            online_softmax_step,
+                                            resolve_interpret, scale_rows)
+
 __all__ = ["flash_attention", "paged_prefill_flash"]
 
 NEG_INF = -1e30
@@ -95,8 +100,9 @@ def flash_attention(
     kv_valid: Optional[int] = None,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     B, H, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     g = H // Hkv
@@ -136,23 +142,22 @@ def flash_attention(
 
 
 def _paged_prefill_kernel(pt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
-                          *rest, scale: float, page: int,
+                          *rest, scale: float, page: int, Hkv: int, G: int,
                           window: int, bq: int, quant: bool = False):
-    # quantized pools thread two extra per-(frame, head) scale operands;
-    # the dequant multiply fuses into the f32 upcast of the staged frame
+    # one grid step = one staged frame (every KV head of one page) against
+    # one query block of every head; the KV-head loop runs inside, each
+    # KV head serving its G grouped query heads
     if quant:
         ks_ref, vs_ref, o_ref, acc, m_s, l_s = rest
     else:
         ks_ref = vs_ref = None
         o_ref, acc, m_s, l_s = rest
-    b, iq, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    n_pg = pl.num_programs(3)
+    b, iq, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_pg = pl.num_programs(2)
 
     @pl.when(j == 0)
     def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
+        init_carry(acc, m_s, l_s)
 
     offset = off_ref[b]
     kv_valid = offset + len_ref[b]
@@ -171,30 +176,20 @@ def _paged_prefill_kernel(pt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
     def _():
         q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, (bq, page), 0)
         kv_pos = first_kv + jax.lax.broadcasted_iota(jnp.int32, (bq, page), 1)
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # (bq, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)                # (page, D)
-        if quant:
-            k = k * ks_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, page)
         mask = (q_pos >= kv_pos) & (kv_pos < kv_valid)
         if window:
             mask &= kv_pos > q_pos - window
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_s[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[:, :1] = l_s[:, :1] * corr + p.sum(-1, keepdims=True)
-        m_s[:, :1] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32)                # (page, D)
-        if quant:
-            v = v * vs_ref[0, 0]
-        acc[...] = acc[...] * corr + jax.lax.dot(p, v)
+        for h in range(Hkv):
+            k, v = head_kv(k_ref, v_ref, ks_ref, vs_ref, h)
+            for g in range(G):
+                hq = h * G + g
+                q = q_ref[0, hq].astype(jnp.float32) * scale     # (bq, D)
+                online_softmax_step(q, k, v, mask, acc, m_s, l_s, hq)
 
     @pl.when(j == n_pg - 1)
     def _():
-        l = jnp.maximum(l_s[:, :1], 1e-30)
-        o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
+        for hq in range(Hkv * G):
+            finish_carry(o_ref, acc, l_s, hq)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bq", "interpret"))
@@ -210,7 +205,7 @@ def paged_prefill_flash(
     bq: int = 128,
     k_scales: Optional[jnp.ndarray] = None,   # (N, Hkv) f32 — quantized pool
     v_scales: Optional[jnp.ndarray] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Chunked-prefill flash attention reading KV through the page table.
 
@@ -219,16 +214,16 @@ def paged_prefill_flash(
     that sequence's pool *frames* through VMEM — the page-table row is a
     scalar-prefetch operand whose dereference picks the frame each step
     DMAs, exactly like ``decode_attention.paged_decode_attention`` but
-    with a (bq, page) score tile instead of one query token.  Per-row
-    ``offset``/``lengths`` (also scalar-prefetched) shift the causal
-    wedge to each row's absolute position, so one call serves chunk rows
-    of different sequences at different prefill depths.  Frames past a
-    row's written extent are skipped by block liveness and never even
-    issue their DMA.
+    with a (bq, page) score tile per query head instead of one query
+    token.  Per-row ``offset``/``lengths`` (also scalar-prefetched)
+    shift the causal wedge to each row's absolute position, so one call
+    serves chunk rows of different sequences at different prefill
+    depths.  Frames past the last one a query block may attend re-use
+    that frame's block index (no DMA) and are skipped by block liveness.
     """
+    interpret = resolve_interpret(interpret)
     C, H, T, D = q.shape
     N, page, Hkv, _ = k_pages.shape
-    g = H // Hkv
     pages_per_seq = page_rows.shape[1]
     bq = min(bq, T)
     pad_t = (-T) % bq
@@ -239,41 +234,43 @@ def paged_prefill_flash(
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
         T = T + pad_t
 
+    def frame(c, i, j, pt, off, ln):
+        # the last position query block i may attend: its own last query,
+        # or the row's written extent if that ends first
+        reach = jnp.minimum(off[c] + ln[c], off[c] + (i + 1) * bq)
+        return pt[c, frame_slot(j, reach, page)]
+
     quant = k_scales is not None
     kernel = functools.partial(_paged_prefill_kernel,
-                               scale=1.0 / math.sqrt(D), page=page,
-                               window=window, bq=bq, quant=quant)
+                               scale=1.0 / math.sqrt(D), page=page, Hkv=Hkv,
+                               G=H // Hkv, window=window, bq=bq, quant=quant)
     in_specs = [
-        pl.BlockSpec((1, 1, bq, D),
-                     lambda b, h, i, j, pt, off, ln: (b, h, i, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, i, j, pt, off, ln, g=g:
-                     (pt[b, j], 0, h // g, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, i, j, pt, off, ln, g=g:
-                     (pt[b, j], 0, h // g, 0)),
+        pl.BlockSpec((1, H, bq, D),
+                     lambda c, i, j, pt, off, ln: (c, 0, i, 0)),
+        pl.BlockSpec((1, page, Hkv, D),
+                     lambda c, i, j, pt, off, ln:
+                     (frame(c, i, j, pt, off, ln), 0, 0, 0)),
+        pl.BlockSpec((1, page, Hkv, D),
+                     lambda c, i, j, pt, off, ln:
+                     (frame(c, i, j, pt, off, ln), 0, 0, 0)),
     ]
     operands = [q, k_pages, v_pages]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1),
-                         lambda b, h, i, j, pt, off, ln, g=g:
-                         (pt[b, j], h // g)),
-            pl.BlockSpec((1, 1),
-                         lambda b, h, i, j, pt, off, ln, g=g:
-                         (pt[b, j], h // g)),
-        ]
-        operands += [k_scales, v_scales]
+        spec = pl.BlockSpec((1, 1, Hkv),
+                            lambda c, i, j, pt, off, ln:
+                            (frame(c, i, j, pt, off, ln), 0, 0))
+        in_specs += [spec, spec]
+        operands += [scale_rows(k_scales), scale_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(C, H, T // bq, pages_per_seq),
+        grid=(C, T // bq, pages_per_seq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, D),
-                               lambda b, h, i, j, pt, off, ln: (b, h, i, 0)),
+        out_specs=pl.BlockSpec((1, H, bq, D),
+                               lambda c, i, j, pt, off, ln: (c, 0, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, _LANE), jnp.float32),
-            pltpu.VMEM((bq, _LANE), jnp.float32),
+            pltpu.VMEM((H, bq, D), jnp.float32),
+            pltpu.VMEM((H, bq, _LANE), jnp.float32),
+            pltpu.VMEM((H, bq, _LANE), jnp.float32),
         ],
     )
     out = pl.pallas_call(

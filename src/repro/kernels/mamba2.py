@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import resolve_interpret
+
 __all__ = ["ssd"]
 
 
@@ -77,7 +79,7 @@ def ssd(
     D: jnp.ndarray,            # (H,)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     B, T, H, P = x.shape
     N = Bm.shape[-1]
@@ -104,6 +106,6 @@ def ssd(
         out_specs=pl.BlockSpec((1, 1, c, P), lambda b, h, j: (b, h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xT, dtT, Bm, Cm, A2, D2)
     return out.transpose(0, 2, 1, 3)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import resolve_interpret
+
 __all__ = ["gather_rows", "gather_blocks"]
 
 
@@ -52,7 +54,7 @@ def gather_rows(
     idx: jnp.ndarray,          # (M,) int32
     *,
     rows_per_block: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     N, d = src.shape
     M = idx.shape[0]
@@ -62,7 +64,7 @@ def gather_rows(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(M // rows_per_block,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((rows_per_block, d), lambda i, idx_ref: (i, 0)),
         scratch_shapes=[pltpu.VMEM((1, d), src.dtype),
                         pltpu.SemaphoreType.DMA],
@@ -71,7 +73,7 @@ def gather_rows(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, d), src.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, src)
 
 
@@ -86,7 +88,7 @@ def gather_blocks(
     block_idx: jnp.ndarray,    # (Mb,) int32 — indices of row-blocks
     *,
     block_rows: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Block-aligned gather: the index map itself reads the prefetched
     scalar indices, so the compiler pipelines the DMAs (large-granularity
@@ -105,5 +107,5 @@ def gather_blocks(
         _gather_blocks_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mb * block_rows, d), src.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_idx, src)

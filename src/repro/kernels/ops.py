@@ -8,6 +8,13 @@
   * ``"auto"``      — pallas on TPU, xla elsewhere.
 
 Model code calls these wrappers; tests sweep ``interpret`` vs ``xla``.
+
+Mosaic kernels cannot be partitioned by GSPMD, so under a mesh whose
+``model`` axis spans several devices the three paged-attention kernels
+run inside ``shard_map`` over the KV-head axis — the axis the pool
+frames are sharded on (``dist.steps.paged_cache_specs``): each device
+attends its own KV heads and their grouped query heads, with the page
+table and lengths replicated.
 """
 
 from __future__ import annotations
@@ -17,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as _ref
 from repro.kernels.amu_matmul import amu_matmul as _amu_matmul
@@ -80,6 +88,59 @@ def decode_attention(q, k, v, *, valid_len=None, impl: str = "auto", **kw):
                         interpret=(impl == "interpret"), **kw)
 
 
+def _gathered_view(k_pages, v_pages, rows, k_scales, v_scales):
+    """The XLA paths' dense (R, pages_per_seq * page, Hkv, D) view of
+    each row's frames, dequantized when the pool is quantized (so the
+    interpret==xla anchors compare like with like)."""
+    R = rows.shape[0]
+    _, page, Hkv, D = k_pages.shape
+    k = jnp.take(k_pages, rows, axis=0)            # (R, pps, page, Hkv, D)
+    v = jnp.take(v_pages, rows, axis=0)
+    if k_scales is not None:
+        ks = jnp.take(k_scales, rows, axis=0)      # (R, pps, Hkv)
+        vs = jnp.take(v_scales, rows, axis=0)
+        k = k.astype(jnp.float32) * ks[:, :, None, :, None]
+        v = v.astype(jnp.float32) * vs[:, :, None, :, None]
+    Skv = rows.shape[1] * page
+    return k.reshape(R, Skv, Hkv, D), v.reshape(R, Skv, Hkv, D)
+
+
+def _paged_kernel(kernel, q, q_heads: int, k_pages, v_pages, control,
+                  k_scales, v_scales, **kw):
+    """Call a page-gather kernel as it must run under the active mesh.
+
+    Without a multi-device mesh this is the plain call.  Otherwise the
+    call runs in ``shard_map``: the query's head axis (``q_heads``), the
+    pool frames' KV-head axis and the scales' head axis split over
+    ``model`` when the KV heads divide it (each device attends its own
+    KV heads and their grouped query heads; contiguous blocks keep the
+    GQA grouping), and are replicated otherwise.  The ``control``
+    operands (page table, offsets, lengths) are always replicated."""
+    from repro.dist.act_sharding import _current_mesh
+
+    quant = k_scales is not None
+    n_ctl = len(control)
+
+    def call(q, kp, vp, *rest):
+        ks, vs = rest[n_ctl:] if quant else (None, None)
+        return kernel(q, kp, vp, *rest[:n_ctl], k_scales=ks, v_scales=vs,
+                      **kw)
+
+    operands = (q, k_pages, v_pages, *control) + \
+        ((k_scales, v_scales) if quant else ())
+    mesh = _current_mesh()
+    if mesh is None or mesh.size == 1:
+        return call(*operands)
+    model = mesh.shape.get("model", 1)
+    ax = "model" if model > 1 and k_pages.shape[2] % model == 0 else None
+    q_spec = P(*(ax if d == q_heads else None for d in range(q.ndim)))
+    pool = P(None, None, ax, None)
+    in_specs = (q_spec, pool, pool) + (P(),) * n_ctl + \
+        ((P(None, ax),) * 2 if quant else ())
+    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                         out_specs=q_spec, check_vma=False)(*operands)
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            impl: str = "auto", k_scales=None, v_scales=None,
                            **kw):
@@ -87,8 +148,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     page_table: (B, pages_per_seq) frame ids; lengths: (B,) valid KV.
 
     ``k_scales``/``v_scales`` (N, Hkv) f32 mark a quantized pool: the
-    XLA path dequantizes the *gathered view* (so the interpret==xla
-    anchor still compares like with like), the Pallas path fuses the
+    XLA path dequantizes the gathered view, the Pallas path fuses the
     per-(frame, head) multiply into the gather kernel."""
     impl = resolve_impl(impl)
     if impl == "xla":
@@ -97,22 +157,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         # paged and dense decode paths stay bit-exact
         from repro.models.attention import one_token_attention
         B, H, D = q.shape
-        _, page, Hkv, _ = k_pages.shape
-        k = jnp.take(k_pages, page_table, axis=0)         # (B, pps, page, ...)
-        v = jnp.take(v_pages, page_table, axis=0)
-        if k_scales is not None:
-            ks = jnp.take(k_scales, page_table, axis=0)   # (B, pps, Hkv)
-            vs = jnp.take(v_scales, page_table, axis=0)
-            k = k.astype(jnp.float32) * ks[:, :, None, :, None]
-            v = v.astype(jnp.float32) * vs[:, :, None, :, None]
-        Skv = k.shape[1] * page
-        k = k.reshape(B, Skv, Hkv, D)
-        v = v.reshape(B, Skv, Hkv, D)
-        out = one_token_attention(q, k, v, lengths, Hkv)
+        k, v = _gathered_view(k_pages, v_pages, page_table, k_scales,
+                              v_scales)
+        out = one_token_attention(q, k, v, lengths, k_pages.shape[2])
         return out.reshape(B, H, D).astype(q.dtype)
-    return _paged_decode_attn(q, k_pages, v_pages, page_table, lengths,
-                              k_scales=k_scales, v_scales=v_scales,
-                              interpret=(impl == "interpret"), **kw)
+    return _paged_kernel(_paged_decode_attn, q, 1, k_pages, v_pages,
+                         (page_table, lengths), k_scales, v_scales,
+                         interpret=(impl == "interpret"), **kw)
 
 
 def paged_verify_attention(q, k_pages, v_pages, page_table, lengths, *,
@@ -132,22 +183,13 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, lengths, *,
     if impl == "xla":
         from repro.models.attention import multi_token_attention
         B, S, H, D = q.shape
-        _, page, Hkv, _ = k_pages.shape
-        k = jnp.take(k_pages, page_table, axis=0)         # (B, pps, page, ...)
-        v = jnp.take(v_pages, page_table, axis=0)
-        if k_scales is not None:
-            ks = jnp.take(k_scales, page_table, axis=0)   # (B, pps, Hkv)
-            vs = jnp.take(v_scales, page_table, axis=0)
-            k = k.astype(jnp.float32) * ks[:, :, None, :, None]
-            v = v.astype(jnp.float32) * vs[:, :, None, :, None]
-        Skv = k.shape[1] * page
-        k = k.reshape(B, Skv, Hkv, D)
-        v = v.reshape(B, Skv, Hkv, D)
-        out = multi_token_attention(q, k, v, lengths, Hkv)
+        k, v = _gathered_view(k_pages, v_pages, page_table, k_scales,
+                              v_scales)
+        out = multi_token_attention(q, k, v, lengths, k_pages.shape[2])
         return out.reshape(B, S, H, D).astype(q.dtype)
-    return _paged_verify_attn(q, k_pages, v_pages, page_table, lengths,
-                              k_scales=k_scales, v_scales=v_scales,
-                              interpret=(impl == "interpret"), **kw)
+    return _paged_kernel(_paged_verify_attn, q, 2, k_pages, v_pages,
+                         (page_table, lengths), k_scales, v_scales,
+                         interpret=(impl == "interpret"), **kw)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_rows, offset, lengths,
@@ -169,25 +211,14 @@ def paged_prefill_attention(q, k_pages, v_pages, page_rows, offset, lengths,
     impl = resolve_impl(impl)
     if impl == "xla":
         from repro.models.attention import chunked_attention
-        C, T, H, D = q.shape
-        _, page, Hkv, _ = k_pages.shape
-        k = jnp.take(k_pages, page_rows, axis=0)       # (C, pps, page, ...)
-        v = jnp.take(v_pages, page_rows, axis=0)
-        if k_scales is not None:
-            ks = jnp.take(k_scales, page_rows, axis=0)  # (C, pps, Hkv)
-            vs = jnp.take(v_scales, page_rows, axis=0)
-            k = k.astype(jnp.float32) * ks[:, :, None, :, None]
-            v = v.astype(jnp.float32) * vs[:, :, None, :, None]
-        Skv = k.shape[1] * page
-        k = k.reshape(C, Skv, Hkv, D)
-        v = v.reshape(C, Skv, Hkv, D)
+        k, v = _gathered_view(k_pages, v_pages, page_rows, k_scales,
+                              v_scales)
         return chunked_attention(q, k, v, causal=True, window=window,
                                  q_offset=offset)
-    qT = q.transpose(0, 2, 1, 3)                       # (C, H, T, D)
-    out = _paged_prefill_flash(qT, k_pages, v_pages, page_rows, offset,
-                               lengths, window=window,
-                               k_scales=k_scales, v_scales=v_scales,
-                               interpret=(impl == "interpret"), **kw)
+    out = _paged_kernel(_paged_prefill_flash, q.transpose(0, 2, 1, 3), 1,
+                        k_pages, v_pages, (page_rows, offset, lengths),
+                        k_scales, v_scales, window=window,
+                        interpret=(impl == "interpret"), **kw)
     return out.transpose(0, 2, 1, 3)
 
 

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.decode_attention import resolve_interpret
+
 __all__ = ["wkv6"]
 
 
@@ -80,7 +82,7 @@ def wkv6(
     u: jnp.ndarray,            # (H, K)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     B, T, H, K = r.shape
     V = v.shape[-1]
@@ -103,6 +105,6 @@ def wkv6(
         out_specs=pl.BlockSpec((1, 1, c, V), lambda b, h, j: (b, h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, V), r.dtype),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rT, kT, vT, wT, u)
     return out.transpose(0, 2, 1, 3)

@@ -17,21 +17,18 @@ import math
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_mesh_compat", "make_production_mesh", "make_test_mesh", "HW"]
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` across jax versions: newer jax wants explicit
-    ``axis_types=(AxisType.Auto, ...)`` for GSPMD-propagated axes; older
-    jax (<= 0.4.x) has neither the enum nor the kwarg."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...],
+                     devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (GSPMD
+    propagates shardings along them).  ``devices`` defaults to
+    ``jax.devices()``; pass a subset to build a mesh on part of a host."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 class HW:

@@ -39,18 +39,39 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke
-from repro.models.model import init_params
+from repro.dist.steps import init_params_sharded
+from repro.launch.mesh import make_mesh_compat
 from repro.serve.config import add_config_args, config_from_args
 from repro.serve.disagg import (make_shared_tier, run_disaggregated,
                                 spool_load, spool_save, tier_pager_factory)
 from repro.serve.engine import Engine
 from repro.serve.workload import WorkloadSpec, generate
+
+
+#: the checkout this module runs from (src/repro/launch/serve.py)
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache(root: Path = REPO_ROOT) -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (JAX reads the
+    variable itself; nothing else is set here).  Otherwise the cache is
+    ``<root>/.jax_cache`` — a fixed path, since the path is part of what
+    a later process must match to find its entries again."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(root) / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _submit_requests(eng, args, cfg, econf, rng) -> None:
@@ -227,8 +248,12 @@ def main(argv=None):
         overrides["scheduler_policy"] = "slo"
     econf = config_from_args(args, **overrides)
 
+    use_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    mesh = econf.mesh if econf.mesh is not None else \
+        make_mesh_compat((1, 1), ("data", "model"))
+    econf = dataclasses.replace(econf, mesh=mesh)
+    params = init_params_sharded(cfg, mesh, jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
 
     if args.disagg:

@@ -41,46 +41,6 @@ __all__ = [
 NEG_INF = -1e30
 
 
-def _gather_qkv_for_rope(q, k, v):
-    """Work around a jax-0.4.37 SPMD miscompile: rope applied to a
-    model-sharded projection comes out scaled by exactly the data-axis
-    size on some mesh shapes (observed at (2, 4); see the ROADMAP
-    record).  Decode/chunk projections are at most a few tokens per
-    slot, so gathering them to replicated before rope costs noise next
-    to the step's weight traffic.  No-op without an active mesh —
-    single-device graphs (and the dense-vs-paged bit-exactness they
-    anchor) are untouched."""
-    from repro.dist import act_sharding as acts
-    return (acts.constrain(q, P()), acts.constrain(k, P()),
-            acts.constrain(v, P()))
-
-
-def _pin_qkv_for_rope(q, k, v, seq_len: int):
-    """The full-sequence (prefill / train) variant of the same SPMD
-    workaround.  Replicating a whole 32k-token projection per layer —
-    what the decode path does — would be a real cost here, so instead
-    q/k/v are *pinned to an explicit layout* through rope: the layout
-    :func:`chunked_attention`'s plan would pick anyway (head-sharded
-    over the model axis, or sequence-sharded under a Megatron-SP
-    residual), falling back to heads-over-model when no plan is active
-    (GSPMD pads a non-dividing head count).  The explicit annotation is
-    what stops the partitioner from mis-placing the rope subgraph; no
-    data moves that attention would not have moved anyway.  No-op
-    without a mesh or without a model axis."""
-    from repro.dist import act_sharding as acts
-    if acts.model_axis_size() <= 1:
-        return q, k, v
-    pol = acts.current()
-    dp = acts.dp_spec_prefix()
-    plan = acts.attn_plan(q.shape[2], k.shape[2], seq_len)
-    if plan is not None and plan[0] == "seq":
-        spec = P(dp, plan[1], None, None)
-    else:
-        spec = P(dp, None, pol.model_axis, None)
-    return (acts.constrain(q, spec), acts.constrain(k, spec),
-            acts.constrain(v, spec))
-
-
 # -- parameter init -------------------------------------------------------------
 
 def attn_init(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
@@ -297,7 +257,6 @@ def attention_block(
     hd = cfg.head_dim
     if kv is None:
         q, k, v = _project_qkv(p, cfg, x, compute_dtype)
-        q, k, v = _pin_qkv_for_rope(q, k, v, S)
         q, k = _position_encode(cfg, q, k, positions)
     else:  # cross attention: k/v from encoder output, no rope on cross path
         q = dense(p["q"], x, compute_dtype).reshape(B, S, cfg.num_heads, hd)
@@ -349,7 +308,6 @@ def decode_attention_block(
     kc, vc = layer_cache
     slots = kc.shape[1]
     q, k_new, v_new = _project_qkv(p, cfg, x, compute_dtype)
-    q, k_new, v_new = _gather_qkv_for_rope(q, k_new, v_new)
     pos = jnp.broadcast_to(pos, (B,))
     posv = pos[:, None]                              # (B, 1)
     if cfg.mrope_sections:
@@ -372,21 +330,15 @@ def one_token_attention(q, kc, vc, valid, num_kv_heads: int):
 
     THE XLA reference for decode attention: shared by the dense decode
     block and (through ``kernels.ops.paged_decode_attention``'s gather)
-    the paged block, so the two layouts stay bit-exact — identical
-    expressions, identical shapes.  ``q``: (B, H, D); ``valid``: (B,)
-    masks KV positions at/past it.  Returns f32 (B, 1, H * D).
+    the paged block.  It is :func:`multi_token_attention` with one query
+    row, so a decode step and row ``s`` of a speculative verify step run
+    the same expression chain: spelling the two out separately let
+    XLA's CPU backend order their reductions differently (1-ulp f32
+    differences).  ``q``: (B, H, D); ``valid``: (B,) masks KV positions
+    at/past it.  Returns f32 (B, 1, H * D).
     """
-    B, H, hd = q.shape
-    slots = kc.shape[1]
-    qf = (q.astype(jnp.float32) * (1.0 / math.sqrt(hd)))
-    qf = qf.reshape(B, num_kv_heads, H // num_kv_heads, hd)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qf, kc.astype(jnp.float32))
-    kv_idx = jnp.arange(slots)
-    s = jnp.where((kv_idx[None, :] < valid[:, None])[:, None, None, :],
-                  s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgk,bkhd->bhgd", w, vc.astype(jnp.float32))
-    return out.reshape(B, 1, H * hd)
+    return multi_token_attention(q[:, None], kc, vc, valid[:, None],
+                                 num_kv_heads)
 
 
 def _one_token_attention(cfg: ModelConfig, q, kc, vc, valid):
@@ -399,15 +351,14 @@ def _one_token_attention(cfg: ModelConfig, q, kc, vc, valid):
 def multi_token_attention(q, kc, vc, valid, num_kv_heads: int):
     """S-query-row attention over a dense (B, Skv, Hkv, D) cache.
 
-    :func:`one_token_attention` generalised to ``S`` query rows per
-    sequence — the XLA reference for speculative verify-K decode.  The
-    expression chain is kept IDENTICAL to the one-token path (scale
-    before the score einsum, mask, softmax, then the value einsum) with
-    one extra batch axis, because token-exactness of speculative decode
-    rests on row ``s`` here being bit-equal to what a sequential
-    one-token step at position ``pos + s`` would compute.  Online-
-    softmax variants (``_chunked_core``) are NOT bit-compatible — they
-    normalise after the value product.
+    The XLA reference for speculative verify-K decode, and (with
+    ``S = 1``) for one-token decode: token-exactness of speculative
+    decode rests on row ``s`` here being bit-equal to what a sequential
+    one-token step at position ``pos + s`` computes, which holds because
+    both are this one expression chain (scale before the score einsum,
+    mask, softmax, then the value einsum).  Online-softmax variants
+    (``_chunked_core``) are NOT bit-compatible — they normalise after
+    the value product.
 
     ``q``: (B, S, H, D); ``valid``: (B, S) masks KV positions at/past it
     independently per row (row ``s`` of a verify step may see ``s`` more
@@ -521,7 +472,6 @@ def paged_decode_attention_block(
     page = kp.shape[1]
     slots = page_table.shape[1] * page           # token capacity per sequence
     q, k_new, v_new = _project_qkv(p, cfg, x, compute_dtype)
-    q, k_new, v_new = _gather_qkv_for_rope(q, k_new, v_new)
     pos = jnp.broadcast_to(pos, (B,))
     posv = pos[:, None]                              # (B, 1)
     if cfg.mrope_sections:
@@ -597,7 +547,6 @@ def paged_verify_block(
     slots = pages_per_seq * page                 # token capacity per sequence
     trash = kp.shape[0] - 1
     q, k_new, v_new = _project_qkv(p, cfg, x, compute_dtype)
-    q, k_new, v_new = _gather_qkv_for_rope(q, k_new, v_new)
     pos = jnp.broadcast_to(pos, (B,))
     abs_pos = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
     if cfg.mrope_sections:
@@ -681,7 +630,6 @@ def paged_prefill_block(
     slots = pages_per_seq * page                 # token capacity per sequence
     trash = kp.shape[0] - 1
     q, k_new, v_new = _project_qkv(p, cfg, x, compute_dtype)
-    q, k_new, v_new = _gather_qkv_for_rope(q, k_new, v_new)
     q, k_new = _position_encode(cfg, q, k_new, positions)
 
     # scatter the chunk's K/V into its mapped pool frames: token t of row

@@ -439,7 +439,11 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
     ``k_scales``/``v_scales`` carried alongside; ``None``/"none" keeps
     the bf16 layout with no extra keys (bit-identical pytree).
     """
-    base = init_cache(cfg, batch, max_len, src_len=src_len)
+    # the non-KV state (SSM, cross-attn, positions) of the dense cache;
+    # built under jit so its dense KV — (L, batch, max_len, Hkv, D), as
+    # large as the model's weights at serving sizes — is never allocated
+    base = jax.jit(lambda: init_cache(cfg, batch, max_len,
+                                      src_len=src_len)._replace(kv={}))()
     fam = cfg.family
     if fam not in ("dense", "moe", "encdec", "hybrid"):
         raise ValueError(f"family {fam!r} has no KV to page")

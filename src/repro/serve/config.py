@@ -246,7 +246,8 @@ class EngineConfig:
         "(comma-separated on the CLI)")
     greedy: bool = _f(True, "greedy sampling", cli=False)
     kernel_impl: str = _f(
-        "auto", "paged-attention backend",
+        "auto", "paged-attention backend (auto: Pallas on TPU, the XLA "
+        "reference elsewhere; a TPU engine refuses interpret/xla)",
         choices=("auto", "pallas", "interpret", "xla"))
     mesh: Any = _f(None, "jax device mesh for the sharded step",
                    cli=False)

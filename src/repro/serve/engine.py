@@ -115,6 +115,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.dist.steps import make_mixed_step, make_serve_step
+from repro.kernels import ops
 from repro.kernels.kv_quant import KVQuantConfig
 from repro.launch.mesh import make_mesh_compat
 from repro.models import ssm as ssm_mod
@@ -166,7 +167,8 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
     far tier for later :meth:`fetch_finished` reuse;
     ``paging.enabled=False`` is the dense A/B reference;
     ``kernel_impl`` selects the paged-attention backend
-    (``auto``/``pallas``/``interpret``/``xla``);
+    (``auto``/``pallas``/``interpret``/``xla``; on a TPU only ``auto``
+    and ``pallas`` are accepted);
     ``paging.pager_factory`` injects a custom
     :class:`~repro.paging.Pager` (tests use a simulated-latency AMU
     backend); ``scheduler.policy="slo"`` switches scheduling from
@@ -185,6 +187,14 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
         if legacy_kwargs:
             config = engine_config_from_kwargs(config, **legacy_kwargs)
         ec = config or EngineConfig()
+        if ops.on_tpu() and ec.kernel_impl in ("interpret", "xla"):
+            # on a TPU the serving path runs the compiled Pallas kernels;
+            # the interpreter and the XLA references stay callable for
+            # comparisons, never as the engine's path
+            raise ValueError(
+                f"kernel_impl={ec.kernel_impl!r} on a TPU backend: the "
+                "engine serves through the compiled Pallas kernels there "
+                "(use 'auto' or 'pallas')")
         pg, ck, sc = ec.paging, ec.chunking, ec.scheduler
         max_batch, max_len = ec.max_batch, ec.max_len
         self.config = ec

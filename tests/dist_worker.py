@@ -93,9 +93,9 @@ def mode_serve_step():
 def mode_prefill_equality():
     """Full-sequence prefill on a (2, 4) mesh must match single-device
     prefill — the regression guard for the jax-0.4.37 SPMD rope
-    miscompile on the prefill/train path (attention._pin_qkv_for_rope):
-    without the explicit layout pin, layer-0 k comes back scaled by
-    exactly the data-axis size (2x) on this mesh shape."""
+    miscompile on the prefill/train path: there, rope on a
+    model-sharded projection brought layer-0 k back scaled by exactly
+    the data-axis size (2x) on this mesh shape."""
     from repro.configs import get_smoke
     from repro.configs.base import ShapeConfig
     from repro.dist.steps import make_prefill_step

@@ -13,8 +13,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.amu import (AMU, AccessConfig, AMUError, FAILURE_CODE, QoS,
-                            QueueFullPolicy, RequestState, SimBackend)
+from repro.core.amu import (AMU, AccessConfig, AMUError, DeviceTransferBackend,
+                            FAILURE_CODE, QoS, QueueFullPolicy, RequestState,
+                            SimBackend)
 from repro.core.offload import FarMemoryTier, StreamingPrefetcher
 from repro.core.patterns import (GatherPattern, StreamPattern, StridePattern,
                                  coalescing_ratio, granules)
@@ -145,3 +146,12 @@ def test_far_tier_prefetch_overlap():
     vals = [pf.step()[0] for _ in range(6)]
     assert vals == [float(i) for i in range(6)]
     assert pf.fetch_overlap_events == 3   # depth kept full while consuming
+
+
+def test_device_backend_memory_kind_is_honoured_or_refused():
+    """A transfer lands in the memory kind asked for; a kind the device
+    lacks raises instead of quietly landing somewhere else."""
+    backend = DeviceTransferBackend()
+    assert backend._sharding("pinned_host").memory_kind == "pinned_host"
+    with pytest.raises(ValueError, match="no memory kind"):
+        backend._sharding("no_such_memory")

@@ -159,10 +159,9 @@ def test_mixed_step_other_families(arch):
 
 def test_mixed_step_on_mesh_matches_dense_mesh_engine(setup):
     """On a real (2, 4) mesh the chunk-queue engine matches the legacy
-    dense engine running on the same mesh (this is also the regression
-    guard for the rope-over-sharded-projection SPMD workaround —
-    without ``_gather_qkv_for_rope`` the chunk K comes out scaled by
-    the data-axis size and every token diverges)."""
+    dense engine running on the same mesh (also a guard against the
+    rope-over-sharded-projection SPMD miscompile jax 0.4.37 had, which
+    scaled the chunk K by the data-axis size so every token diverged)."""
     import jax as _jax
     if len(_jax.devices()) < 8:
         pytest.skip("needs 8 forced host devices")

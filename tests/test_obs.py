@@ -39,8 +39,12 @@ from repro.paging import (EventKind, PagePool, PageState, PageTable, Pager,
 from repro.paging.sim import simulate_paged_serving
 from repro.serve import (ChunkingConfig, Engine, EngineConfig, PagingConfig,
                          SchedulerConfig, VirtualClock)
-from repro.serve.config import ObsConfig
+from repro.serve.config import ObsConfig, SpeculationConfig
 from repro.serve.workload import WorkloadSpec, generate
+# imported here, not inside the property test that uses it: importing a
+# module that defines @given tests from inside a running @given test is
+# hypothesis's nested-given health-check failure
+from tests.test_spec_decode import _proposer_factory
 
 # tools/trace_report.py is deliberately standalone (stdlib only, no repro
 # import) so CI can run it on artifacts; load it here by path.
@@ -321,9 +325,6 @@ def test_property_trace_reproduces_slo_report(setup, seed, device_pages):
 # -- speculation accounting: trace-derived == the engine's own ----------------
 
 def _run_spec_traced(cfg, params, seed, kind):
-    from repro.serve.config import SpeculationConfig
-    from tests.test_spec_decode import _proposer_factory
-
     rng = np.random.default_rng(seed)
     requests = [(rng.integers(0, cfg.vocab_size,
                               int(rng.integers(4, 13))).astype(np.int32),

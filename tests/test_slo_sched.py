@@ -5,8 +5,9 @@ The scheduler redesign's acceptance surface (PR 6):
 
   * whatever the SLO policy decides — EDF chunk ordering, batch-tier
     shedding, deadline-aware preemption onto QoS windows — the tokens
-    generated must equal the dense engine's bit-for-bit (scheduling
-    changes *when*, never *what*),
+    generated must equal those of the same engine run without
+    scheduling pressure, bit for bit (scheduling changes *when*, never
+    *what*),
   * batch-tier requests are shed first under pressure but never starve:
     every admitted request completes,
   * under overload the SLO policy's interactive goodput must dominate
@@ -20,7 +21,7 @@ import warnings
 import jax
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.configs import get_smoke
 from repro.models import init_params
@@ -51,15 +52,22 @@ def _trace_requests(cfg, seed, n=6):
     return out
 
 
-def _dense_reference(cfg, params, cache, reqs):
-    key = tuple((tuple(int(t) for t in p), wr.output_len) for wr, p in reqs)
+def _unpressured_reference(cfg, params, cache, reqs, page_size):
+    """The same chunked paged engine (same page size and chunk length,
+    so the same compute shapes) with a pool holding every slot's whole
+    capacity, FIFO scheduling and every request submitted at once:
+    nothing is shed, parked or preempted."""
+    key = ("chunked", page_size,
+           tuple((tuple(int(t) for t in p), wr.output_len) for wr, p in reqs))
     if key not in cache:
         eng = Engine(cfg, params, EngineConfig(
             max_batch=3, max_len=64, prefill_buckets=(16,),
-            paging=PagingConfig(enabled=False)))
+            paging=PagingConfig(page_size=page_size),
+            chunking=ChunkingConfig(chunk_tokens=4)))
         for wr, prompt in reqs:
             eng.submit(prompt, max_new_tokens=wr.output_len)
         cache[key] = eng.run()
+        assert eng.stats["preemptions"] == 0
     return cache[key]
 
 
@@ -67,14 +75,26 @@ def _dense_reference(cfg, params, cache, reqs):
 @given(seed=st.integers(0, 2**16),
        page_size=st.sampled_from([4, 8]),
        spare_pages=st.integers(0, 2))
+# an exact bf16 tie between two logits of request 2 at position 14: a
+# 1-ulp difference between the dense-prefill and chunked-prefill
+# programs flips its argmax, so the dense engine is no reference here
+@example(seed=62240, page_size=4, spare_pages=0)
 def test_property_slo_schedule_token_exact(setup, seed, page_size,
                                            spare_pages):
     """Random traces on a pool tight enough to force shedding and
-    deadline-aware preemption: the SLO scheduler's outputs must equal
-    the dense engine's token-for-token, and page accounting must drain."""
+    deadline-aware preemption: the SLO scheduler's outputs must equal,
+    token for token, those of the same engine run without any
+    scheduling pressure — scheduling changes *when*, never *what* — and
+    page accounting must drain.
+
+    The reference shares the engine's compute shapes.  The dense
+    engine does not: its whole-prompt prefill reduces in a different
+    order than 4-token chunks, and on XLA's CPU backend that moves the
+    last bit of some activations, which flips the argmax wherever two
+    bf16 logits tie exactly (the pinned example)."""
     cfg, params, ref_cache = setup
     reqs = _trace_requests(cfg, seed)
-    ref = _dense_reference(cfg, params, ref_cache, reqs)
+    ref = _unpressured_reference(cfg, params, ref_cache, reqs, page_size)
 
     need = max(pages_for(min(len(p) + wr.output_len, 64), page_size)
                for wr, p in reqs)
