@@ -294,21 +294,25 @@ def make_serve_step(cfg: ModelConfig, mesh, shape: ShapeConfig, *,
                 kv[name], NamedSharding(mesh, spec))
         return cache._replace(kv=kv)
 
+    # the function's name names the program's module in a profile
+    # (jit_serve_decode, jit_serve_verify): device time splits by program
     if speculate_k:
-        def step(params, cache, tokens, length):
+        def serve_verify(params, cache, tokens, length):
             params = _constrain_tree(params, pspecs, mesh)
             cache = _constrain_cache(cache)
             with acts.policy(pol):
                 return model_mod.verify_step(params, cfg, cache, tokens,
                                              length, impl=kernel_impl)
+        step = serve_verify
     else:
-        def step(params, cache, tokens):
+        def serve_decode(params, cache, tokens):
             params = _constrain_tree(params, pspecs, mesh)
             if cspecs is not None:
                 cache = _constrain_cache(cache)
             with acts.policy(pol):
                 return model_mod.decode_step(params, cfg, cache, tokens,
                                              impl=kernel_impl)
+        step = serve_decode
 
     fn = jax.jit(step, donate_argnums=(1,) if donate else ())
     specs = {"params": pspecs}
@@ -380,8 +384,9 @@ def make_mixed_step(cfg: ModelConfig, mesh, shape: ShapeConfig, *,
                 x, NamedSharding(mesh, P())), chunk)
         return params, cache, chunk
 
+    # named as its module appears in a profile (see make_serve_step)
     if speculate_k:
-        def step(params, cache, tokens, length, chunk):
+        def serve_mixed_verify(params, cache, tokens, length, chunk):
             params, cache, chunk = _constrain(params, cache, chunk)
             with acts.policy(pol):
                 logits, cache = model_mod.verify_step(
@@ -389,8 +394,9 @@ def make_mixed_step(cfg: ModelConfig, mesh, shape: ShapeConfig, *,
                 chunk_logits, cache, carry = model_mod.prefill_chunk(
                     params, cfg, cache, chunk, impl=kernel_impl)
             return logits, chunk_logits, carry, cache
+        step = serve_mixed_verify
     else:
-        def step(params, cache, tokens, chunk):
+        def serve_mixed(params, cache, tokens, chunk):
             params, cache, chunk = _constrain(params, cache, chunk)
             with acts.policy(pol):
                 logits, cache = model_mod.decode_step(
@@ -398,6 +404,7 @@ def make_mixed_step(cfg: ModelConfig, mesh, shape: ShapeConfig, *,
                 chunk_logits, cache, carry = model_mod.prefill_chunk(
                     params, cfg, cache, chunk, impl=kernel_impl)
             return logits, chunk_logits, carry, cache
+        step = serve_mixed
 
     fn = jax.jit(step, donate_argnums=(1,) if donate else ())
     return _MeshedStep(fn, mesh), {"params": pspecs, "paged_cache": cspecs}

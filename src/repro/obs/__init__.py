@@ -1,11 +1,20 @@
 """repro.obs — unified telemetry across AMU → pager → engine.
 
-Zero-dependency observability riding the one shared
-:class:`~repro.serve.config.VirtualClock`:
+Observability on the engine's clock: its own
+:class:`~repro.serve.config.VirtualClock` by default, or the wall clock
+a deployment injects (``SchedulerConfig.clock``):
 
   * :class:`Tracer` — structured spans/instants/counter samples for
-    every AMU transfer, pager action, page residency transition, and
-    engine request lifecycle event (default-off-cheap: one branch),
+    every pager action, page residency transition, engine request
+    lifecycle event and, on the virtual clock, every simulated AMU
+    transfer (default-off-cheap: one branch).  ``Tracer.span`` times
+    the engine's step phases and frame transfers as they happen; with
+    ``Tracer.profile`` set each such span is also a
+    ``jax.profiler.TraceAnnotation``, so it lands on the host plane of
+    a profiler trace beside the device's operations (``profile`` alone
+    writes only those annotations).  On an injected
+    clock the AMU stays unbound: its simulated ticks are not wall time
+    (no AMU spans, no ``amu/latency_s`` histogram),
   * :class:`MetricsRegistry` — counters, gauges, and log-bucketed
     :class:`Histogram` percentiles (p50/p95/p99/max); the subsystem
     ``stats`` Counters are now :class:`CounterView` windows onto it,

@@ -195,10 +195,13 @@ class Pager:
         self._blocked_note: Dict[Tuple[Hashable, int], float] = {}
         self.bind_obs(self.metrics, tracer)
 
-    def bind_obs(self, metrics=None, tracer=None) -> None:
+    def bind_obs(self, metrics=None, tracer=None, amu: bool = True) -> None:
         """Bind this pager (and its AMU, windows, page table) to a shared
         registry + tracer — the engine calls this so factory-built pagers
-        land on the engine's clock/registry.  Existing counts migrate."""
+        land on the engine's clock/registry.  Existing counts migrate.
+        With ``amu=False`` the AMU is unbound instead (no spans, no
+        ``amu/latency_s`` histogram): its simulated ticks are not the
+        clock of a caller that runs on the wall clock."""
         if metrics is not None and metrics is not self.metrics:
             fresh = metrics.counters("pager")
             for k, v in self.stats.items():
@@ -207,10 +210,14 @@ class Pager:
             self.stats = fresh
         if tracer is not None:
             self.tracer = tracer
-            self.amu.tracer = tracer
             self.table.tracer = tracer
-        if self.amu.metrics is None or metrics is not None:
-            self.amu.metrics = self.metrics
+        if not amu:
+            self.amu.tracer, self.amu.metrics = NULL_TRACER, None
+        else:
+            if tracer is not None:
+                self.amu.tracer = tracer
+            if self.amu.metrics is None or metrics is not None:
+                self.amu.metrics = self.metrics
         self.windows.bind_obs(self.stats, self.tracer)
 
     def _now(self) -> float:

@@ -191,105 +191,110 @@ class AdmissionMixin:
         return far
 
     def _admit(self) -> None:
-        if self.paging:
-            self._try_finish_resumes()
-        now = self.clock()
-        self.sched.order_queue(self.queue, now)
-        while self.queue:
-            req = self.queue[0]
-            if req.arrival_t > now:
-                break                 # trace replay: not in the system yet
-            if req.parked:                                # preempted: resume
-                if req.rid in self._resuming or not self._start_resume(req):
+        with self.tracer.span("engine", "admit"):
+            if self._resuming:
+                with self.tracer.span("engine", "resume"):
+                    self._try_finish_resumes()
+            now = self.clock()
+            self.sched.order_queue(self.queue, now)
+            while self.queue:
+                req = self.queue[0]
+                if req.arrival_t > now:
+                    break                 # trace replay: not in the system yet
+                if req.parked:                         # preempted: resume
+                    if req.rid in self._resuming or \
+                            not self._start_resume(req):
+                        break
+                    self.queue.pop(0)
+                    with self.tracer.span("engine", "resume"):
+                        self._try_finish_resumes()
+                    continue
+                if not self.pool.n_free:
                     break
+                hits: List[int] = []
+                if self.paging:
+                    need = pages_for(min(len(req.prompt), self.slot_tokens),
+                                     self.page_size)
+                    if self.prefix is not None and self._chunkable(req) \
+                            and req.rid not in self.page_table.sequences():
+                        hits = self.prefix.match(req.prompt)
+                        # device-resident hits take no new frames
+                        need -= sum(
+                            1 for l in hits if self.prefix.entry_state(l)
+                            is PageState.RESIDENT)
+                    if not self.sched.may_admit(req, need):
+                        # SLO load shedding: the highest-priority admissible
+                        # request is batch-tier and the pool is too tight to
+                        # take it without risking interactive deadlines
+                        self.stats["shed_admissions"] += 1
+                        if self.tracer.enabled:
+                            self.tracer.instant(
+                                "engine", "sched", "shed",
+                                {"rid": req.rid, "tier": req.tier.name,
+                                 "need_pages": need,
+                                 "free": self.page_pool.n_free})
+                        break
+                    if not self.policy.can_admit(self.page_pool, need) and \
+                            not self._make_room(need + self.policy.low,
+                                                frozenset(), preempt=False):
+                        break
+                if hits and self._admit_prefix(req, hits):
+                    # far-tier hits: request left at the queue head, parked;
+                    # the next iteration routes it through _start_resume
+                    continue
                 self.queue.pop(0)
-                self._try_finish_resumes()
-                continue
-            if not self.pool.n_free:
-                break
-            hits: List[int] = []
-            if self.paging:
-                need = pages_for(min(len(req.prompt), self.slot_tokens),
-                                 self.page_size)
-                if self.prefix is not None and self._chunkable(req) \
-                        and req.rid not in self.page_table.sequences():
-                    hits = self.prefix.match(req.prompt)
-                    # device-resident hits take no new frames
-                    need -= sum(
-                        1 for l in hits
-                        if self.prefix.entry_state(l) is PageState.RESIDENT)
-                if not self.sched.may_admit(req, need):
-                    # SLO load shedding: the highest-priority admissible
-                    # request is batch-tier and the pool is too tight to
-                    # take it without risking interactive deadlines
-                    self.stats["shed_admissions"] += 1
-                    if self.tracer.enabled:
-                        self.tracer.instant(
-                            "engine", "sched", "shed",
-                            {"rid": req.rid, "tier": req.tier.name,
-                             "need_pages": need,
-                             "free": self.page_pool.n_free})
-                    break
-                if not self.policy.can_admit(self.page_pool, need) and \
-                        not self._make_room(need + self.policy.low,
-                                            frozenset(), preempt=False):
-                    break
-            if hits and self._admit_prefix(req, hits):
-                # far-tier hits: request left at the queue head, parked;
-                # the next iteration routes it through _start_resume
-                continue
-            self.queue.pop(0)
-            slot = self.pool.alloc()
-            req.slot = slot
-            if self._chunkable(req):
-                # chunk-queue admission: install bookkeeping only — the
-                # prompt is computed chunk-by-chunk by the mixed step,
-                # interleaved with every running slot's decode
-                if req.rid not in self.page_table.sequences():
+                slot = self.pool.alloc()
+                req.slot = slot
+                req.admit_t = self.clock()
+                if self._chunkable(req):
+                    # chunk-queue admission: install bookkeeping only — the
+                    # prompt is computed chunk-by-chunk by the mixed step,
+                    # interleaved with every running slot's decode
+                    if req.rid not in self.page_table.sequences():
+                        self.page_table.register(req.rid)
+                    req.target_len = len(req.prompt)
+                    req.chunk_rows = np.full((self.pages_per_seq,),
+                                             self.trash_frame, np.int32)
+                    # prefix hits already mapped: pin them for the slot and
+                    # point the chunk row at the shared frames
+                    for logical in range(self.page_table.n_pages(req.rid)):
+                        self.page_table.pin_page(req.rid, logical)
+                        req.chunk_rows[logical] = \
+                            self.page_table.entry(req.rid, logical).phys
+                    if self.cfg.family == "hybrid":
+                        req.chunk_ssm = jax.tree_util.tree_map(
+                            np.copy, self._zero_chunk_ssm)
+                    if self.cfg.family == "encdec":
+                        self._install_cross(req)
+                    req.admit_seq = next(self._admits)
+                    self.prefilling[slot] = req
+                    self.stats["admitted"] += 1
+                    self._obs_phase(req, "prefill")
+                    self.events.post(EventKind.ADMIT, req.rid)
+                    continue
+                logits, single = self._prefill_one(req)
+                if self.paging:
                     self.page_table.register(req.rid)
-                req.target_len = len(req.prompt)
-                req.chunk_rows = np.full((self.pages_per_seq,),
-                                         self.trash_frame, np.int32)
-                # prefix hits already mapped: pin them for the slot and
-                # point the chunk row at the shared frames
-                for logical in range(self.page_table.n_pages(req.rid)):
-                    self.page_table.pin_page(req.rid, logical)
-                    req.chunk_rows[logical] = \
-                        self.page_table.entry(req.rid, logical).phys
-                if self.cfg.family == "hybrid":
-                    req.chunk_ssm = jax.tree_util.tree_map(
-                        np.copy, self._zero_chunk_ssm)
-                if self.cfg.family == "encdec":
-                    self._install_cross(req)
+                    self._alloc_pinned(req,
+                                       min(len(req.prompt), self.slot_tokens))
+                    self._install_sequence(req, single)
+                else:
+                    self.cache = insert_slot(self.cache, single, slot,
+                                             self.max_batch)
                 req.admit_seq = next(self._admits)
-                self.prefilling[slot] = req
+                first = int(np.argmax(np.asarray(logits)[0]))
+                req.generated.append(first)
+                req.first_token_t = self.clock()
+                req.token_ts.append(req.first_token_t)
+                self.active[slot] = req
                 self.stats["admitted"] += 1
-                self._obs_phase(req, "prefill")
+                self._obs_phase(req, "decode")
+                if self.tracer.enabled:
+                    self.tracer.instant(
+                        "requests", f"req{req.rid}", "first_token",
+                        {"ttft_s": req.first_token_t - req.arrival_t})
                 self.events.post(EventKind.ADMIT, req.rid)
-                continue
-            logits, single = self._prefill_one(req)
-            if self.paging:
-                self.page_table.register(req.rid)
-                self._alloc_pinned(req,
-                                   min(len(req.prompt), self.slot_tokens))
-                self._install_sequence(req, single)
-            else:
-                self.cache = insert_slot(self.cache, single, slot,
-                                         self.max_batch)
-            req.admit_seq = next(self._admits)
-            first = int(np.argmax(np.asarray(logits)[0]))
-            req.generated.append(first)
-            req.first_token_t = self.clock()
-            req.token_ts.append(req.first_token_t)
-            self.active[slot] = req
-            self.stats["admitted"] += 1
-            self._obs_phase(req, "decode")
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    "requests", f"req{req.rid}", "first_token",
-                    {"ttft_s": req.first_token_t - req.arrival_t})
-            self.events.post(EventKind.ADMIT, req.rid)
-            self._finish_if_done(req)
+                self._finish_if_done(req)
 
     # -- cross-engine handoff admission (DECODE role) --------------------------
     def admit_handoff(self, rec: HandoffRecord,

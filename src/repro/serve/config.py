@@ -212,9 +212,14 @@ class SchedulerConfig:
 
 @dataclass(frozen=True)
 class ObsConfig:
-    """Telemetry (:mod:`repro.obs`): the tracer rides the engine's one
-    :class:`VirtualClock`, so AMU transfer spans, pager actions, and
-    request lifecycle tracks share a single deterministic time axis.
+    """Telemetry (:mod:`repro.obs`): the tracer rides the engine's
+    clock.  On the default :class:`VirtualClock`, AMU transfer spans,
+    pager actions, step spans and request lifecycle tracks share one
+    deterministic time axis; on an injected wall clock the step and
+    frame-transfer spans time real host work and the AMU's simulated
+    transfers are left untraced.  Setting ``engine.tracer.profile``
+    writes the step and frame-transfer spans into a running
+    ``jax.profiler`` trace, alone or beside the in-memory events.
     Tracing is off by default and costs one branch per call site when
     off; ``trace_out``/``metrics_out`` imply enabling it and write the
     Perfetto-loadable timeline / flat metrics JSON when ``run()``

@@ -225,17 +225,21 @@ class DecodeMixin:
         return drafts
 
     def _step(self) -> None:
-        drafts = self._propose_drafts() \
-            if self.speculating and self.active else {}
-        if self.paging:
-            # draft-aware growth: a speculating slot pins frames for its
-            # whole write window [pos, pos + 1 + draft); entries clamp
-            # in place when the pool cannot cover the full draft
-            self._ensure_growth(drafts or None)
-        picks = self._select_chunks() if self.chunking else []
-        if self.chunking and not picks and not self.active and \
-                self.prefilling and not self._resuming:
-            picks = self._force_chunk()
+        tr = self.tracer
+        drafts: Dict[int, int] = {}
+        if self.speculating and self.active:
+            with tr.span("engine", "prepare"):
+                drafts = self._propose_drafts()
+        with tr.span("engine", "grow"):
+            if self.paging:
+                # draft-aware growth: a speculating slot pins frames for
+                # its whole write window [pos, pos + 1 + draft); entries
+                # clamp in place when the pool cannot cover the draft
+                self._ensure_growth(drafts or None)
+            picks = self._select_chunks() if self.chunking else []
+            if self.chunking and not picks and not self.active and \
+                    self.prefilling and not self._resuming:
+                picks = self._force_chunk()
         if not self.active and not picks:
             return
         if drafts:
@@ -243,45 +247,52 @@ class DecodeMixin:
             # (its draft dies with the park) or clamped a draft to zero
             live = {req.rid for req in self.active.values()}
             drafts = {r: n for r, n in drafts.items() if r in live and n > 0}
-        if self.paging and self._pt_dirty:
-            # refresh the device page-table rows from the host mirror
-            # (skipped on steady-state steps with no scheduling events)
-            kv = self.cache.kv
-            self.cache = self.cache._replace(
-                kv=dict(kv, page_table=jnp.asarray(self._pt_np)))
-            self._pt_dirty = False
+        with tr.span("engine", "prepare"):
+            if self.paging and self._pt_dirty:
+                # refresh the device page-table rows from the host mirror
+                # (skipped on steady-state steps with no scheduling events)
+                kv = self.cache.kv
+                self.cache = self.cache._replace(
+                    kv=dict(kv, page_table=jnp.asarray(self._pt_np)))
+                self._pt_dirty = False
+            chunk = self._build_chunk(picks) if picks else None
+            if not drafts:
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                for slot, req in self.active.items():
+                    toks[slot, 0] = req.generated[-1]
+                toks = jnp.asarray(toks)
         if drafts:
-            self._spec_step(drafts, picks)
+            self._spec_step(drafts, picks, chunk)
             return
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        for slot, req in self.active.items():
-            toks[slot, 0] = req.generated[-1]
+        with tr.span("engine", "launch"):
+            if picks:
+                logits, chunk_logits, carry, self.cache = self._mixed(
+                    self.params, self.cache, toks, chunk)
+            else:
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  toks)
         if picks:
-            chunk = self._build_chunk(picks)
-            logits, chunk_logits, carry, self.cache = self._mixed(
-                self.params, self.cache, jnp.asarray(toks), chunk)
             self.stats["mixed_steps"] += 1
             self.stats["chunks"] += len(picks)
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(toks))
         self.stats["steps"] += 1
-        if self.active:
-            logits = np.asarray(logits)
+        with tr.span("engine", "wait_device"):
+            jax.block_until_ready(logits)
+        with tr.span("engine", "logits_to_host"):
+            logits = np.asarray(logits) if self.active else None
+            chunk_logits = np.asarray(chunk_logits) if picks else None
+        with tr.span("engine", "sample"):
             t_now = self.clock()
-            tr = self.tracer
             for slot, req in list(self.active.items()):
                 nxt = int(np.argmax(logits[slot]))
                 req.generated.append(nxt)
                 req.token_ts.append(t_now)
-                if tr.enabled:
-                    tr.instant("requests", f"req{req.rid}", "token",
-                               {"n": len(req.generated)})
                 self._finish_if_done(req)
         if picks:
-            self._finish_chunks(picks, np.asarray(chunk_logits), carry)
+            with tr.span("engine", "finish_chunks"):
+                self._finish_chunks(picks, chunk_logits, carry)
 
-    def _spec_step(self, drafts: Dict[int, int], picks: List) -> None:
+    def _spec_step(self, drafts: Dict[int, int], picks: List,
+                   chunk) -> None:
         """One speculative verify-K step: score every slot's draft in a
         single jitted program, then accept/rollback host-side.
 
@@ -299,29 +310,48 @@ class DecodeMixin:
         future appends, and excluded from a later park's freshness tag
         because parks derive valid tokens from ``pos``)."""
         S = self.speculate_k + 1
-        toks = np.zeros((self.max_batch, S), np.int32)
-        length = np.zeros((self.max_batch,), np.int32)
-        per_slot: Dict[int, List[int]] = {}
-        for slot, req in self.active.items():
-            d = self._draft_toks.get(req.rid, [])[:drafts.get(req.rid, 0)]
-            per_slot[slot] = d
-            toks[slot, 0] = req.generated[-1]
-            toks[slot, 1:1 + len(d)] = d
-            length[slot] = 1 + len(d)
+        tr = self.tracer
+        with tr.span("engine", "prepare"):
+            toks = np.zeros((self.max_batch, S), np.int32)
+            length = np.zeros((self.max_batch,), np.int32)
+            per_slot: Dict[int, List[int]] = {}
+            for slot, req in self.active.items():
+                n = drafts.get(req.rid, 0)
+                d = self._draft_toks.get(req.rid, [])[:n]
+                per_slot[slot] = d
+                toks[slot, 0] = req.generated[-1]
+                toks[slot, 1:1 + len(d)] = d
+                length[slot] = 1 + len(d)
+            toks, length = jnp.asarray(toks), jnp.asarray(length)
+        with tr.span("engine", "launch"):
+            if picks:
+                logits, chunk_logits, carry, self.cache = \
+                    self._mixed_verify(self.params, self.cache, toks,
+                                       length, chunk)
+            else:
+                logits, self.cache = self._verify(self.params, self.cache,
+                                                  toks, length)
         if picks:
-            chunk = self._build_chunk(picks)
-            logits, chunk_logits, carry, self.cache = self._mixed_verify(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(length), chunk)
             self.stats["mixed_steps"] += 1
             self.stats["chunks"] += len(picks)
-        else:
-            logits, self.cache = self._verify(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(length))
         self.stats["steps"] += 1
         self.stats["spec_steps"] += 1
-        logits = np.asarray(logits)
+        with tr.span("engine", "wait_device"):
+            jax.block_until_ready(logits)
+        with tr.span("engine", "logits_to_host"):
+            logits = np.asarray(logits)
+            chunk_logits = np.asarray(chunk_logits) if picks else None
+        with tr.span("engine", "sample"):
+            self._accept_drafts(logits, per_slot)
+        if picks:
+            with tr.span("engine", "finish_chunks"):
+                self._finish_chunks(picks, chunk_logits, carry)
+
+    def _accept_drafts(self, logits: np.ndarray,
+                       per_slot: Dict[int, List[int]]) -> None:
+        """Greedy acceptance of every slot's draft against the verify
+        logits, position rewinds, and the finish path (see
+        :meth:`_spec_step`)."""
         t_now = self.clock()
         tr = self.tracer
         pos_np = np.array(self.cache.pos)
@@ -339,9 +369,6 @@ class DecodeMixin:
                 req.generated.append(int(t))
                 req.token_ts.append(t_now)
                 appended += 1
-                if tr.enabled:
-                    tr.instant("requests", f"req{req.rid}", "token",
-                               {"n": len(req.generated)})
                 if self._role_done(req):
                     break
             committed = min(acc, appended)   # drafts actually appended
@@ -372,8 +399,6 @@ class DecodeMixin:
             tr.counter("engine", "spec_drafted", self.stats["drafted"])
             tr.counter("engine", "spec_accepted", self.stats["accepted"])
             tr.counter("engine", "spec_rejected", self.stats["rejected"])
-        if picks:
-            self._finish_chunks(picks, np.asarray(chunk_logits), carry)
 
     def _finish_if_done(self, req: Request) -> None:
         if not self._role_done(req):

@@ -294,9 +294,12 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
                 self.pager.read_frame = self._read_frame
             # adopt the pager (factory-built or not) into the engine's
             # registry + tracer: its ad-hoc stats migrate into the
-            # "pager" counter group and its AMU/page-table emit spans on
-            # the engine clock
-            self.pager.bind_obs(self.metrics, self.tracer)
+            # "pager" counter group and its page table emits on the
+            # engine clock.  The AMU's simulated ticks are that clock
+            # only when the engine owns it: on an injected clock the
+            # AMU is left unbound, its spans and latencies not wall time
+            self.pager.bind_obs(self.metrics, self.tracer,
+                                amu=self._own_clock)
             # THE far tier: one FarMemoryTier behind the pager holds
             # every cold page — preempted, watermark-evicted, finished —
             # plus finished sequences' aux residues and the prefix
@@ -529,42 +532,46 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
         disaggregated driver (:func:`~repro.serve.disagg.
         run_disaggregated`) can interleave two engines; :meth:`run` is
         this in a drain loop."""
-        self._admit()
-        if self.active or self.prefilling:
-            self._step()
-        self.events.tick()
-        if not self.active and not self.prefilling and self._resuming:
-            # nothing decodable: land the in-flight pages, then
-            # demand-fetch the head resume so the loop always
-            # progresses (its misses may evict other resumes' pages)
-            for req in list(self._resuming.values()):
-                self.pager.wait_arriving(req.rid)
-            self.pager.wait_seq(next(iter(self._resuming.values())).rid)
+        with self.tracer.span("engine", "step"):
             self._admit()
-        if not self.active and not self.prefilling \
-                and not self._resuming and self.queue:
-            # everything just finished this step: retry admission
-            # now rather than waiting for the next iteration
-            self._admit()
+            if self.active or self.prefilling:
+                self._step()
+            with self.tracer.span("engine", "tick"):
+                self.events.tick()
+            if not self.active and not self.prefilling and self._resuming:
+                # nothing decodable: land the in-flight pages, then
+                # demand-fetch the head resume so the loop always
+                # progresses (its misses may evict other resumes' pages)
+                with self.tracer.span("engine", "resume"):
+                    for req in list(self._resuming.values()):
+                        self.pager.wait_arriving(req.rid)
+                    self.pager.wait_seq(
+                        next(iter(self._resuming.values())).rid)
+                self._admit()
             if not self.active and not self.prefilling \
-                    and not self._resuming:
-                future = [r.arrival_t for r in self.queue
-                          if r.arrival_t > self.clock()]
-                if future and len(future) == len(self.queue):
-                    # the system is idle only because the trace is:
-                    # fast-forward the virtual clock to the next
-                    # arrival (a wall clock advances by itself)
-                    if self._own_clock:
-                        self.clock.advance(min(future) - self.clock())
-                    return
-                # nothing running and nothing in flight: the state
-                # can never change, so admission is blocked for
-                # good — fail loudly instead of spinning to max_steps
-                raise PagingError(
-                    f"{len(self.queue)} queued requests can never be "
-                    "admitted (free pages "
-                    f"{self.page_pool.n_free if self.paging else 'n/a'}"
-                    f", low watermark {self.policy.low})")
+                    and not self._resuming and self.queue:
+                # everything just finished this step: retry admission
+                # now rather than waiting for the next iteration
+                self._admit()
+                if not self.active and not self.prefilling \
+                        and not self._resuming:
+                    future = [r.arrival_t for r in self.queue
+                              if r.arrival_t > self.clock()]
+                    if future and len(future) == len(self.queue):
+                        # the system is idle only because the trace is:
+                        # fast-forward the virtual clock to the next
+                        # arrival (a wall clock advances by itself)
+                        if self._own_clock:
+                            self.clock.advance(min(future) - self.clock())
+                        return
+                    # nothing running and nothing in flight: the state
+                    # can never change, so admission is blocked for
+                    # good — fail loudly instead of spinning to max_steps
+                    raise PagingError(
+                        f"{len(self.queue)} queued requests can never be "
+                        "admitted (free pages "
+                        f"{self.page_pool.n_free if self.paging else 'n/a'}"
+                        f", low watermark {self.policy.low})")
 
     def run(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         """Event loop until every submitted request completes.

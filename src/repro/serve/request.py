@@ -49,6 +49,7 @@ class Request:
     generated: List[int] = field(default_factory=list)
     slot: Optional[int] = None
     submitted_t: float = 0.0
+    admit_t: Optional[float] = None     # first given a slot; a resume keeps it
     first_token_t: float = 0.0
     done_t: float = 0.0
     token_ts: List[float] = field(default_factory=list)  # one per token

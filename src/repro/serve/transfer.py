@@ -151,16 +151,18 @@ class TransferMixin:
     def _read_frame(self, phys: int) -> Dict[str, np.ndarray]:
         """Pull one frame's content (L, page, Hkv, D) off the device —
         the page-granularity transfer unit the pager's astores move."""
-        kv = self.cache.kv
-        out = {"k": np.asarray(kv["k_pages"][:, phys]),
-               "v": np.asarray(kv["v_pages"][:, phys])}
-        if "k_scales" in kv:
-            # quantized pool: the (L, Hkv) scale pair rides every page
-            # payload — parked pages, far-tier entries, prefix homes and
-            # disagg handoffs all move quantized bytes + scales
-            out["k_scale"] = np.asarray(kv["k_scales"][:, phys])
-            out["v_scale"] = np.asarray(kv["v_scales"][:, phys])
-        return out
+        with self.tracer.span("transfer", "read_frame", phys=phys,
+                              nbytes=self.pager.page_nbytes):
+            kv = self.cache.kv
+            out = {"k": np.asarray(kv["k_pages"][:, phys]),
+                   "v": np.asarray(kv["v_pages"][:, phys])}
+            if "k_scales" in kv:
+                # quantized pool: the (L, Hkv) scale pair rides every page
+                # payload — parked pages, far-tier entries, prefix homes and
+                # disagg handoffs all move quantized bytes + scales
+                out["k_scale"] = np.asarray(kv["k_scales"][:, phys])
+                out["v_scale"] = np.asarray(kv["v_scales"][:, phys])
+            return out
 
     def _land_frame(self, phys: int) -> None:
         """If the pool frame holds a far-tier payload that has not been
@@ -168,24 +170,27 @@ class TransferMixin:
         frame = self.page_pool.frames[phys]
         if frame.data is None:
             return                       # content already lives in the pool
-        kv = self.cache.kv
-        if "k_scales" in kv:
-            kp, vp, ks, vs = _scatter_one_page_q(
-                kv["k_pages"], kv["v_pages"],
-                kv["k_scales"], kv["v_scales"],
-                jnp.asarray(frame.data["k"]), jnp.asarray(frame.data["v"]),
-                jnp.asarray(frame.data["k_scale"]),
-                jnp.asarray(frame.data["v_scale"]),
-                jnp.asarray(phys, jnp.int32))
-            self.cache = self.cache._replace(kv=dict(
-                kv, k_pages=kp, v_pages=vp, k_scales=ks, v_scales=vs))
-        else:
-            kp, vp = _scatter_one_page(
-                kv["k_pages"], kv["v_pages"],
-                jnp.asarray(frame.data["k"]), jnp.asarray(frame.data["v"]),
-                jnp.asarray(phys, jnp.int32))
-            self.cache = self.cache._replace(
-                kv=dict(kv, k_pages=kp, v_pages=vp))
+        with self.tracer.span("transfer", "land_frame", phys=phys,
+                              nbytes=self.pager.page_nbytes):
+            kv = self.cache.kv
+            data = frame.data
+            if "k_scales" in kv:
+                kp, vp, ks, vs = _scatter_one_page_q(
+                    kv["k_pages"], kv["v_pages"],
+                    kv["k_scales"], kv["v_scales"],
+                    jnp.asarray(data["k"]), jnp.asarray(data["v"]),
+                    jnp.asarray(data["k_scale"]),
+                    jnp.asarray(data["v_scale"]),
+                    jnp.asarray(phys, jnp.int32))
+                self.cache = self.cache._replace(kv=dict(
+                    kv, k_pages=kp, v_pages=vp, k_scales=ks, v_scales=vs))
+            else:
+                kp, vp = _scatter_one_page(
+                    kv["k_pages"], kv["v_pages"],
+                    jnp.asarray(data["k"]), jnp.asarray(data["v"]),
+                    jnp.asarray(phys, jnp.int32))
+                self.cache = self.cache._replace(
+                    kv=dict(kv, k_pages=kp, v_pages=vp))
         frame.data = None
 
     # -- paging helpers -------------------------------------------------------
@@ -223,10 +228,12 @@ class TransferMixin:
         if not victims or len(self.active) + len(self.prefilling) <= 1:
             return False
         victim = self.sched.pick_victim(victims, self.clock())
-        if victim.mid_prefill:
-            self._park_prefilling(victim)
-        else:
-            self._park(victim)
+        with self.tracer.span("engine", "park", rid=victim.rid,
+                              frames=self.page_table.n_pages(victim.rid)):
+            if victim.mid_prefill:
+                self._park_prefilling(victim)
+            else:
+                self._park(victim)
         return True
 
     def _shed_pages(self, req: Request, valid: int,
@@ -371,6 +378,8 @@ class TransferMixin:
             # (preemption/resume stats must stay balanced)
             first_admit = req.admit_seq < 0
             req.admit_seq = next(self._admits)
+            if first_admit:
+                req.admit_t = self.clock()
             if req.mid_prefill:
                 req.chunk_rows = rows
                 if self.cfg.family == "encdec":

@@ -225,3 +225,24 @@ def test_mixed_batch_sweep_ttft_improves():
     # the gain grows with load: continuous batching is a queueing win
     r4 = simulate_mixed_batching(4.0)
     assert r4["ttft_speedup"] >= r["ttft_speedup"] * 0.95
+
+
+def test_step_programs_carry_their_names(setup):
+    """The decode and mixed programs lower to modules named after their
+    functions (``jit_serve_decode``, ``jit_serve_mixed``): a profile
+    tells them apart by the name of their ``XLA Modules`` events."""
+    cfg, params = setup
+    eng = Engine(cfg, params, EngineConfig(
+        max_batch=2, max_len=64, prefill_buckets=(16,),
+        paging=PagingConfig(page_size=8),
+        chunking=ChunkingConfig(chunk_tokens=8)))
+    toks = jnp.zeros((2, 1), jnp.int32)
+    C = eng.chunk_slots
+    chunk = {"tokens": jnp.zeros((C, 8), jnp.int32),
+             "offset": jnp.zeros((C,), jnp.int32),
+             "length": jnp.zeros((C,), jnp.int32),
+             "page_rows": jnp.zeros((C, eng.pages_per_seq), jnp.int32)}
+    decode = eng._decode.lower(params, eng.cache, toks).as_text()
+    mixed = eng._mixed.lower(params, eng.cache, toks, chunk).as_text()
+    assert "@jit_serve_decode" in decode
+    assert "@jit_serve_mixed" in mixed

@@ -20,9 +20,11 @@ The observability PR's acceptance surface:
     — including AMU fault storms.
 """
 
+import glob
 import importlib.util
 import json
 import pathlib
+import time
 
 import jax
 import numpy as np
@@ -434,3 +436,102 @@ def test_engine_tracing_off_by_default(setup):
     assert snap["counters"]["engine"]["admitted"] == 3
     assert "events" in snap["counters"]       # EventLoop shares the registry
     assert eng.stats["admitted"] == 3         # CounterView reads unchanged
+
+
+# -- Tracer.span: step phases and frame transfers -----------------------------
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_nests_or_costs_nothing(enabled):
+    """Disabled: ``span`` hands back one shared no-op and records
+    nothing.  Enabled: nested ``X`` events on the tracer's clock, each
+    naming the span it opened in as ``parent``."""
+    clock = VirtualClock()
+    tr = Tracer(clock=clock, enabled=enabled)
+    with tr.span("engine", "step") as outer:
+        clock.advance(1.0)
+        with tr.span("transfer", "read_frame", phys=3, nbytes=64) as inner:
+            clock.advance(0.5)
+        clock.advance(0.25)
+    if not enabled:
+        assert outer is inner is tr.span("engine", "other")
+        assert tr.events == [] and not tr._stack
+        return
+    (i_ev, o_ev) = tr.events               # the inner span closes first
+    assert i_ev[:5] == ("X", "transfer", "host", "read_frame", 1.0)
+    assert i_ev[5] == pytest.approx(0.5)
+    assert o_ev[:5] == ("X", "engine", "host", "step", 0.0)
+    assert o_ev[5] == pytest.approx(1.75)
+    assert o_ev[6]["parent"] == 0
+    assert i_ev[6] == {"phys": 3, "nbytes": 64, "id": i_ev[6]["id"],
+                       "parent": o_ev[6]["id"]}
+    assert not tr._stack
+
+
+def _overrun_engine(cfg, params, clock, **obs):
+    """Three slots over a 10-page pool of 8-token pages, no hot tail:
+    every preemption writes its pages back and every resume lands them."""
+    eng = Engine(cfg, params, EngineConfig(
+        max_batch=3, max_len=64, prefill_buckets=(16,),
+        paging=PagingConfig(page_size=8, device_pages=10, hot_tail_pages=0),
+        chunking=ChunkingConfig(chunk_tokens=8),
+        scheduler=SchedulerConfig(clock=clock), obs=ObsConfig(**obs)))
+    rng = np.random.default_rng(0)
+    for _ in range(4):
+        eng.submit(rng.integers(0, cfg.vocab_size, 20).astype(np.int32),
+                   max_new_tokens=16)
+    return eng
+
+
+@pytest.mark.parametrize("own_clock", [True, False])
+def test_amu_bound_only_to_the_engines_own_clock(setup, own_clock):
+    """On its own virtual clock the engine records the simulated AMU's
+    transfer spans and latencies; on an injected wall clock the AMU is
+    left unbound (its ticks are not wall time), while the pager, the
+    step spans and the frame transfers still record."""
+    cfg, params = setup
+    eng = _overrun_engine(cfg, params, None if own_clock else
+                          time.perf_counter, trace=True)
+    eng.run()
+    assert eng.stats["preemptions"] > 0
+    pids = {e[1] for e in eng.tracer.events}
+    hists = [h for h in eng.metrics.snapshot()["histograms"]
+             if h.startswith("amu/latency_s/")]
+    assert ("amu" in pids) is own_clock
+    assert bool(hists) is own_clock
+    assert {"engine", "transfer", "pager"} <= pids
+    # every frame transfer ran inside one of the engine's own spans
+    spans = {e[6]["id"]: e for e in eng.tracer.events
+             if e[0] == "X" and e[2] == "host"}
+    moves = [e for e in spans.values() if e[1] == "transfer"]
+    assert {e[3] for e in moves} == {"read_frame", "land_frame"}
+    assert all(spans[e[6]["parent"]][1] == "engine" for e in moves)
+    assert {e[3] for e in spans.values() if e[1] == "engine"} >= {
+        "step", "admit", "grow", "park", "resume", "prepare", "launch",
+        "wait_device", "logits_to_host", "sample", "finish_chunks", "tick"}
+    # the exported timeline stays valid with the nested step spans in it
+    assert trace_report.validate(eng.export_trace()) == []
+
+
+def test_profile_puts_spans_on_the_profilers_host_plane(setup, tmp_path):
+    """With ``profile`` set, the step and transfer spans are profiler
+    annotations: a ``jax.profiler`` trace of a small overrun run on the
+    CPU holds them on its host plane, named ``<pid>/<name>``.  Set on
+    its own, nothing is kept in the tracer's memory."""
+    from jax.profiler import ProfileData
+    cfg, params = setup
+    eng = _overrun_engine(cfg, params, time.perf_counter)
+    eng.tracer.profile = True
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host") for line in plane.lines
+             for e in line.events}
+    assert {"engine/step", "engine/park", "transfer/read_frame",
+            "transfer/land_frame"} <= names
+    assert eng.tracer.events == [] and not eng.tracer._stack
