@@ -8,12 +8,22 @@ the serving path — decode, speculative verify, chunked prefill — in
 bf16 and int8-pool form at phi4-mini-3.8b's published widths (24 query
 heads, 8 KV heads, head_dim 128, page 16) for a ``v5e:2x2`` topology
 described, not attached, and check that a Mosaic kernel is in the
-program.  Nothing runs; no chip is needed.
+program: at max_len 4096 (256 pages a row) and, for decode and verify, at
+4000 (250 pages, which their blocks of frames do not divide, so a row's
+last block is ragged) and with the 2 (decode also 1) KV heads a device
+holds when the pool is sharded over 4 or 8 devices (where they are fewer
+than the pool dtype's row tile, XLA keeps each frame head-major).  No
+operation outside the kernel may write an array the size of the pool:
+the kernels read the pool as it lies, with no copy, pad or relayout of
+it per call.  Nothing runs; no chip is needed.
 
 The topology is described inside a module fixture (never at import):
 only one process at a time may load the TPU library, and under several
 pytest workers only the worker that runs this file should.
 """
+
+import math
+import re
 
 import pytest
 
@@ -26,8 +36,8 @@ from repro.kernels.decode_attention import (paged_decode_attention,
 from repro.kernels.flash_attention import paged_prefill_flash
 
 # phi4-mini-3.8b widths; the serving shapes chip_smoke.py runs
-H, HKV, D, PAGE = 24, 8, 128, 16
-MAX_BATCH, PAGES_PER_SEQ, N_FRAMES = 16, 4096 // PAGE, 1025
+Q_HEADS, HKV, D, PAGE = 24, 8, 128, 16
+MAX_BATCH, N_FRAMES = 16, 1025
 VERIFY_ROWS = 4                       # speculate_k = 3
 CHUNK_ROWS, CHUNK_TOKENS = 2, 512
 
@@ -56,41 +66,65 @@ def one_chip():
             cc.reset_cache()
 
 
-def _pool_args(sharding, pool_dtype):
+def _pool_args(sharding, pool_dtype, kv_heads):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    pool = s((N_FRAMES, PAGE, HKV, D), pool_dtype)
+    pool = s((N_FRAMES, PAGE, kv_heads, D), pool_dtype)
     scales = {}
     if pool_dtype != jnp.bfloat16:
-        scales = {"k_scales": s((N_FRAMES, HKV), jnp.float32),
-                  "v_scales": s((N_FRAMES, HKV), jnp.float32)}
+        scales = {"k_scales": s((N_FRAMES, kv_heads), jnp.float32),
+                  "v_scales": s((N_FRAMES, kv_heads), jnp.float32)}
     return s, pool, scales
 
 
-def _lower(kernel, pool_dtype, sharding):
-    s, pool, scales = _pool_args(sharding, pool_dtype)
+def _lower(kernel, pool_dtype, sharding, pages_per_seq, kv_heads):
+    s, pool, scales = _pool_args(sharding, pool_dtype, kv_heads)
+    H = kv_heads * (Q_HEADS // HKV)
     if kernel == "decode":
         return paged_decode_attention.lower(
             s((MAX_BATCH, H, D), jnp.bfloat16), pool, pool,
-            s((MAX_BATCH, PAGES_PER_SEQ), jnp.int32),
+            s((MAX_BATCH, pages_per_seq), jnp.int32),
             s((MAX_BATCH,), jnp.int32), interpret=False, **scales)
     if kernel == "verify":
         return paged_verify_attention.lower(
             s((MAX_BATCH, VERIFY_ROWS, H, D), jnp.bfloat16), pool, pool,
-            s((MAX_BATCH, PAGES_PER_SEQ), jnp.int32),
+            s((MAX_BATCH, pages_per_seq), jnp.int32),
             s((MAX_BATCH, VERIFY_ROWS), jnp.int32), interpret=False,
             **scales)
     return paged_prefill_flash.lower(
         s((CHUNK_ROWS, H, CHUNK_TOKENS, D), jnp.bfloat16), pool, pool,
-        s((CHUNK_ROWS, PAGES_PER_SEQ), jnp.int32),
+        s((CHUNK_ROWS, pages_per_seq), jnp.int32),
         s((CHUNK_ROWS,), jnp.int32), s((CHUNK_ROWS,), jnp.int32),
         interpret=False, **scales)
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
-@pytest.mark.parametrize("kernel", ["decode", "verify", "prefill"])
-def test_paged_kernel_compiles_for_v5e(one_chip, kernel, pool_dtype):
-    compiled = _lower(kernel, pool_dtype, one_chip).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+@pytest.mark.parametrize("kernel,max_len,kv_heads", [
+    ("decode", 4096, HKV), ("verify", 4096, HKV), ("prefill", 4096, HKV),
+    ("decode", 4000, HKV), ("verify", 4000, HKV),
+    ("decode", 4096, 2), ("decode", 4096, 1), ("verify", 4096, 2)],
+    ids=["decode", "verify", "prefill", "decode-4000", "verify-4000",
+         "decode-2kv", "decode-1kv", "verify-2kv"])
+def test_paged_kernel_compiles_for_v5e(one_chip, kernel, max_len, kv_heads,
+                                       pool_dtype):
+    compiled = _lower(kernel, pool_dtype, one_chip, max_len // PAGE,
+                      kv_heads).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _pool_sized_ops(text, N_FRAMES * PAGE * kv_heads * D)
+
+
+def _pool_sized_ops(hlo: str, pool_elems: int) -> list:
+    """Instructions that produce an array of at least the pool's size,
+    other than the parameters, their bitcasts, the kernel itself and
+    XLA's asynchronous moves of a whole buffer between memories."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if m and m.group(2) not in ("parameter", "bitcast", "custom-call",
+                                    "copy-start", "copy-done"):
+            if math.prod(int(d) for d in m.group(1).split(",")) >= pool_elems:
+                found.append(line.strip())
+    return found
