@@ -10,9 +10,9 @@ The cell (``BENCHMARK.json``'s ``workloads``) names a model configuration
 
 1. refuses to run, printing no result, unless JAX finds a TPU with as many
    chips as the cell asks for;
-2. makes the bf16 weights on the device from ``--seed`` in one jitted call
-   and builds ``repro.serve.Engine`` on them (Pallas kernels, the default
-   pager, a wall clock);
+2. makes the bf16 weights on the device from ``--seed``, one layer at a
+   time into the engine's shardings, and builds ``repro.serve.Engine`` on
+   them (Pallas kernels, the default pager, a wall clock);
 3. warms up: two requests that compile every step program, then the
    cell's own traffic until the traffic file's warm-up holds;
 4. measures for ``--seconds`` (with ``--trace 1``: a shorter window under
@@ -110,27 +110,73 @@ def model_config(cfg: dict):
         param_dtype="bfloat16", compute_dtype="bfloat16")
 
 
-def make_params(cfg: dict, mcfg, mesh, seed: int):
-    """The bench's weights, made on the device in one jitted call into
-    the shardings the engine's step programs take."""
+def weight_programs(cfg: dict, mcfg, mesh):
+    """The jitted programs that make the bench's weights on ``mesh``, in
+    the shardings the engine's step programs take: ``({top path:
+    program(key)}, zeros(), fill(layers, key, l))``.
+
+    ``fill`` draws layer ``l`` (traced: it compiles once) and writes it
+    into the stacked arrays, which it is given donated; each top leaf has
+    a program of its own.  No program draws more than one layer's leaves
+    or one table, so no whole stacked leaf is ever held in float32 (drawn
+    at once, Mistral-Nemo's stacked MLP leaf alone takes 11.7 GB of
+    float32 on each of four devices)."""
     import jax
+    import jax.numpy as jnp
     from jax.sharding import NamedSharding
     from repro.dist.sharding import param_specs
     from repro.dist.steps import abstract_params
     from bench import weights as W
 
-    key = W.base_key(seed)
+    L = W.dims(cfg)["L"]
+    stacked = {p: (L,) + shape for p, shape, _ in W.layer_leaves(cfg)}
+    tops = {p: shape for p, shape, _ in W.top_leaves(cfg)}
     want = abstract_params(mcfg)
-    mine = jax.eval_shape(lambda k: W.params(cfg, k), key)
-    shape = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: (a.shape, str(a.dtype)), t)
-    if shape(want) != shape(mine):
+    dtype = str(jnp.dtype(W.DTYPE))
+    mine = W.nest({**{p: (s, dtype) for p, s in tops.items()},
+                   **{f"layers/{p}": (s, dtype) for p, s in stacked.items()}})
+    if jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)),
+                              want) != mine:
         raise ValueError("bench/weights.py no longer matches the engine's "
                          "parameter tree")
-    shardings = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s),
-                                       param_specs(mesh, want))
-    return jax.block_until_ready(jax.jit(
-        lambda k: W.params(cfg, k), out_shardings=shardings)(key))
+    specs = param_specs(mesh, want)
+
+    def sharding(path):
+        node = specs
+        for k in path.split("/"):
+            node = node[k]
+        return NamedSharding(mesh, node)
+
+    top = {p: jax.jit(lambda k, p=p: W.top(cfg, k)[p],
+                      out_shardings=sharding(p)) for p in tops}
+    into = {p: sharding(f"layers/{p}") for p in stacked}
+    zeros = jax.jit(lambda: {p: jnp.zeros(s, W.DTYPE)
+                             for p, s in stacked.items()},
+                    out_shardings=into)
+    fill = jax.jit(
+        lambda st, k, l: {p: jax.lax.dynamic_update_index_in_dim(
+            st[p], v, l, 0) for p, v in W.layer(cfg, k, l).items()},
+        donate_argnums=0, out_shardings=into)
+    return top, zeros, fill
+
+
+def make_params(cfg: dict, mcfg, mesh, seed: int):
+    """The bench's weights, made on the device from the seed one layer at
+    a time into the engine's shardings (:func:`weight_programs`).  The
+    values are those of ``bench/weights.py``'s key scheme, which the
+    reference remakes layer by layer."""
+    import jax
+    import jax.numpy as jnp
+    from bench import weights as W
+
+    top, zeros, fill = weight_programs(cfg, mcfg, mesh)
+    key = W.base_key(seed)
+    tree = W.nest({p: program(key) for p, program in top.items()})
+    layers = zeros()
+    for l in range(W.dims(cfg)["L"]):
+        layers = fill(layers, key, jnp.int32(l))
+    tree["layers"] = W.nest(layers)
+    return jax.block_until_ready(tree)
 
 
 def make_engine(cell, mcfg, params, mesh):
@@ -219,6 +265,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     compiles = loop.CompileClock(clock)
 
     params = make_params(cell.config, mcfg, mesh, seed)
+    log(f"[weights] made at {clock() - t_start:.3f} s; peak so far on the "
+        f"fullest chip {memory_peak(devices)} B")
     eng = make_engine(cell, mcfg, params, mesh)
     loop.warm_compile(eng, mcfg.vocab_size, int(e["chunk_tokens"]), seed)
     plan = traffic.make_plan(cell.traffic, mcfg.vocab_size, seed)

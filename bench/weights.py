@@ -3,11 +3,12 @@ benchmark itself.
 
 The tree has the layout the serving engine takes (layer stacks stacked
 on axis 0).  Every leaf is drawn from its own key, and every layer of a
-stacked leaf from ``fold_in(leaf key, layer)``, so the plain reference
-can make one layer at a time and get the same numbers the engine was
-given.  Scales: norms are ones, the embedding and output tables are
-``N(0, 0.02^2)``, each projection ``N(0, 1 / d_in)``.  Values are drawn
-in float32 and rounded once to the served dtype (bfloat16).
+stacked leaf from ``fold_in(leaf key, layer)``, so the engine's tree
+(``bench/run.py:make_params``) and the plain reference are both made
+one layer at a time and hold the same numbers.  Scales: norms are ones,
+the embedding and output tables are ``N(0, 0.02^2)``, each projection
+``N(0, 1 / d_in)``.  Values are drawn in float32 and rounded once to the
+served dtype (bfloat16).
 """
 
 from __future__ import annotations
@@ -91,7 +92,8 @@ def top(cfg, key) -> Dict[str, jnp.ndarray]:
             for i, (path, shape, kind) in enumerate(top_leaves(cfg))}
 
 
-def _nest(flat: Dict[str, Any]) -> Dict[str, Any]:
+def nest(flat: Dict[str, Any]) -> Dict[str, Any]:
+    """``{"a/b": v}`` to ``{"a": {"b": v}}``."""
     out: Dict[str, Any] = {}
     for path, v in flat.items():
         node = out
@@ -100,12 +102,3 @@ def _nest(flat: Dict[str, Any]) -> Dict[str, Any]:
             node = node.setdefault(p, {})
         node[leaf] = v
     return out
-
-
-def params(cfg, key) -> Dict[str, Any]:
-    """The whole tree, layer stacks on axis 0 (trace under ``jax.jit``)."""
-    L = dims(cfg)["L"]
-    stacked = jax.vmap(lambda l: layer(cfg, key, l))(jnp.arange(L))
-    tree = _nest(top(cfg, key))
-    tree["layers"] = _nest(stacked)
-    return tree
