@@ -2,7 +2,6 @@
 """Smoke run of the serving path on TPU: a published model through Engine.
 
     python chip_smoke.py              # one chip: phases (a)-(e)
-    python chip_smoke.py --chips 4    # four chips: the tensor-parallel path
 
 One chip serves phi4-mini-3.8b at its published widths (32 layers,
 d_model 3072, 24/8 heads, head_dim 128, d_ff 8192, vocab 200064) with
@@ -23,12 +22,9 @@ Pallas kernels:
   (e) each paged kernel against its XLA reference on the pool contents
       phases (c) and (d) left.
 
-``--chips 4`` runs only the tensor-parallel path and its comparison:
-mistral-nemo-12b at published widths (24.5 GB in bf16, so four chips)
-served on a (data=1, model=4) mesh, parameters created in their
-shardings and the paged kernels in ``shard_map`` over the KV heads; then
-the same seed cut to 8 layers served on model=4 and on model=1, whose
-prefill and first-decode logits must agree.
+The tensor-parallel path (mistral-nemo-12b over four chips) is served
+and checked against the float32 reference by the benchmark cell
+``nemo-tp4-chat`` (``bench/run.py``).
 
 Times are wall-clock readings of a smoke run, not a benchmark.  Any
 failed phase ends the run with a non-zero exit.  The last line of
@@ -48,13 +44,11 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
 
 ROOT = Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
 
 PHI4 = "phi4-mini-3.8b"
-NEMO = "mistral-nemo-12b"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -296,108 +290,8 @@ def one_chip(cfg, sizes: Sizes, seed: int, clock: CompileClock,
     print(f"[e] peak_bytes_in_use={peak_bytes()}", flush=True)
 
 
-# Tolerance of the four-chip comparison, as max |model=4 - model=1| /
-# max |model=1| over logits.  Activations are bf16: each row-parallel
-# projection on four devices rounds four partial sums to bf16 (2**-8
-# relative) and adds them in another order than one device does, and 8
-# layers compound that.  On four virtual CPU devices the same comparison
-# measured 1.6e-2 to 2.5e-2 (8 layers, d_model 64 to 1024); the limit
-# leaves 4x room for full width.  The faults it guards against — the
-# rope miscompile of jax 0.4.37 scaled K by the data-axis size, a
-# mis-sharded head mixes heads — give errors of order 1.
-TP_TOL = 1e-1
-
-
-def capture_logits(eng):
-    """Record, per request, the graduation (prefill) logits and the
-    logits of its first decode step, as the engine's own programs
-    return them."""
-    prefill, first_decode = {}, {}
-    finalize, steps = eng._finalize_prefill, {}
-
-    def on_finalize(req, logits_row):
-        prefill[req.rid] = np.asarray(logits_row, np.float32)
-        return finalize(req, logits_row)
-
-    def wrap(name):
-        fn = getattr(eng, name)
-
-        def run(*args):
-            slots = {s: r.rid for s, r in eng.active.items()}
-            out = fn(*args)
-            logits = np.asarray(out[0], np.float32)
-            for slot, rid in slots.items():
-                first_decode.setdefault(rid, logits[slot])
-            return out
-        steps[name] = run
-
-    wrap("_decode")
-    wrap("_mixed")
-    eng._finalize_prefill = on_finalize
-    eng._decode, eng._mixed = steps["_decode"], steps["_mixed"]
-    return prefill, first_decode
-
-
-def four_chips(cfg, sizes: Sizes, seed: int, clock: CompileClock,
-               cut_layers: int = 8) -> None:
-    """The tensor-parallel path on a (data=1, model=4) mesh."""
-    from repro.dist.sharding import param_specs
-    from repro.dist.steps import abstract_params, init_params_sharded
-    from repro.launch.mesh import make_mesh_compat
-
-    devices = jax.devices()[:4]
-    mesh4 = make_mesh_compat((1, 4), ("data", "model"), devices=devices)
-    mesh1 = make_mesh_compat((1, 1), ("data", "model"), devices=devices[:1])
-    rng = np.random.default_rng(seed)
-    V = cfg.vocab_size
-
-    params = init_params_sharded(cfg, mesh4, jax.random.PRNGKey(seed))
-    eng = make_engine(cfg, params, mesh4, sizes)
-    serve(f"tp {cfg.name} model=4", eng,
-          random_requests(rng, V, 8, sizes.max_len // 8,
-                          sizes.max_len // 2, 16), clock)
-    del eng, params
-    gc.collect()
-
-    cut = dataclasses.replace(cfg, num_layers=cut_layers)
-    requests = random_requests(rng, V, 4, sizes.chunk_tokens // 2,
-                               2 * sizes.chunk_tokens, 4)
-    params1 = init_params_sharded(cut, mesh1, jax.random.PRNGKey(seed))
-    runs = {}
-    for label, mesh in (("model=1", mesh1), ("model=4", mesh4)):
-        if mesh is mesh4:
-            specs = param_specs(mesh4, abstract_params(cut))
-            params = jax.device_put(params1, jax.tree_util.tree_map(
-                lambda s: NamedSharding(mesh4, s), specs))
-        else:
-            params = params1
-        eng = make_engine(cut, params, mesh, sizes)
-        logs = capture_logits(eng)
-        serve(f"tp {cut_layers}-layer cut {label}", eng, requests, clock)
-        runs[label] = (logs, {r.rid: r.generated[0]
-                              for r in eng.finished.values()})
-        del eng, params
-        gc.collect()
-    (pre1, dec1), first1 = runs["model=1"]
-    (pre4, dec4), first4 = runs["model=4"]
-    check(sorted(pre1) == sorted(pre4) == sorted(first1),
-          "tp: the two runs graduated different requests")
-    same_first = [r for r in first1 if first1[r] == first4[r]]
-    check(same_first, "tp: no request's first token agreed across meshes")
-    for name, a, b, rids in (("prefill", pre4, pre1, sorted(pre1)),
-                             ("first-decode", dec4, dec1, same_first)):
-        err = max(float(np.abs(a[r] - b[r]).max() / np.abs(b[r]).max())
-                  for r in rids)
-        print(f"[tp] {name} logits model=4 vs model=1: rel_err={err:.2e} "
-              f"(tol {TP_TOL:.0e}) over {len(rids)} requests", flush=True)
-        check(err <= TP_TOL, f"tp {name}: rel_err {err:.3e} > {TP_TOL}")
-    print(f"[tp] first tokens equal in {len(same_first)} of {len(first1)} "
-          "requests", flush=True)
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -407,10 +301,6 @@ def main(argv=None) -> int:
         print(f"chip_smoke: JAX found platform {platform!r}, not a TPU; "
               "nothing was run", file=sys.stderr)
         return 2
-    if len(devices) < args.chips:
-        print(f"chip_smoke: --chips {args.chips} needs {args.chips} TPU "
-              f"devices, found {len(devices)}", file=sys.stderr)
-        return 2
 
     from repro.configs import get_config
     from repro.launch.serve import use_compile_cache
@@ -418,12 +308,7 @@ def main(argv=None) -> int:
     print(f"[a] device: {platform} {devices[0].device_kind} x{len(devices)};"
           f" compile cache {use_compile_cache(ROOT)}", flush=True)
     clock = CompileClock()
-    if args.chips == 4:
-        four_chips(bf16(get_config(NEMO)),
-                   Sizes(max_batch=8, max_len=2048, device_pages=None),
-                   args.seed, clock)
-    else:
-        one_chip(bf16(get_config(PHI4)), Sizes(), args.seed, clock)
+    one_chip(bf16(get_config(PHI4)), Sizes(), args.seed, clock)
     print(json.dumps({"ok": True, "device": {
         "platform": platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

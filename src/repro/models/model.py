@@ -427,7 +427,7 @@ class PagedCache(NamedTuple):
 def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
                      n_frames: int, page_size: int,
                      src_len: Optional[int] = None,
-                     quant=None) -> PagedCache:
+                     quant=None, shardings=None) -> PagedCache:
     """Like :func:`init_cache` but with the KV in pool-frame layout.
 
     Frame ``n_frames - 1`` is the *trash frame*: unmapped page-table
@@ -438,6 +438,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
     name) switches the pool frames to int8/fp8 payloads with
     ``k_scales``/``v_scales`` carried alongside; ``None``/"none" keeps
     the bf16 layout with no extra keys (bit-identical pytree).
+    ``shardings`` places the pool arrays (see
+    :func:`~repro.models.attention.init_paged_kv_cache`).
     """
     # the non-KV state (SSM, cross-attn, positions) of the dense cache;
     # built under jit so its dense KV — (L, batch, max_len, Hkv, D), as
@@ -452,7 +454,8 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
         every = cfg.shared_attn_every or cfg.num_layers
         n_layers = cfg.num_layers // every
     kv = init_paged_kv_cache(cfg, n_frames, page_size, batch, max_len,
-                             n_layers=n_layers, quant=quant)
+                             n_layers=n_layers, quant=quant,
+                             shardings=shardings)
     return PagedCache(kv=kv, ssm=base.ssm, cross=base.cross, pos=base.pos)
 
 

@@ -112,9 +112,11 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.dist.steps import make_mixed_step, make_serve_step
+from repro.dist.steps import (make_mixed_step, make_serve_step,
+                              paged_cache_specs)
 from repro.kernels import ops
 from repro.kernels.kv_quant import KVQuantConfig
 from repro.launch.mesh import make_mesh_compat
@@ -231,6 +233,8 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
         self._next_rid = 0
         self._admits = itertools.count()
 
+        self.mesh = ec.mesh if ec.mesh is not None else \
+            make_mesh_compat((1, 1), ("data", "model"))
         # -- page-granularity KV residency over a fixed device pool --------
         # (decided before the decode step is built: the step consumes the
         # paged layout directly when the family has attention KV)
@@ -307,12 +311,17 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
             # pager_factory points two engines' pagers at ONE shared
             # tier (see repro.serve.disagg.tier_pager_factory).
             self.far_tier = self.pager.tier
-            # device frames: pool frames + one trash frame at the end
+            # device frames: pool frames + one trash frame at the end;
+            # made in the shardings the step programs take, so a pool
+            # sharded over the mesh is never whole on one device
             self.trash_frame = n_pages
             self.cache: Any = init_paged_cache(
                 cfg, max_batch, max_len, n_frames=n_pages + 1,
                 page_size=page_size,
-                quant=self.kv_quant if self.kv_quant.enabled else None)
+                quant=self.kv_quant if self.kv_quant.enabled else None,
+                shardings={name: NamedSharding(self.mesh, spec)
+                           for name, spec in
+                           paged_cache_specs(self.mesh, cfg).items()})
             self._pt_np = np.full((max_batch, self.pages_per_seq),
                                   self.trash_frame, np.int32)
             self._pt_dirty = True
@@ -350,8 +359,6 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
         self.deadlines = DeadlineQueue()
 
         # -- mesh-sharded decode step (dist.steps, not a raw jit) ----------
-        self.mesh = ec.mesh if ec.mesh is not None else \
-            make_mesh_compat((1, 1), ("data", "model"))
         shape = ShapeConfig("serve_engine", max_len, max_batch, "decode")
         # cache donated: the step aliases the pool frames in place —
         # no per-token copy of the KV pool (self.cache is rebound to the
