@@ -158,61 +158,9 @@ def decode_attention(
 # row to its own KV head, so no per-head strided slice is taken.  A
 # quantized pool's per-(frame, head) scales travel as one (1, page*Hkv)
 # row per frame, laid out like those score columns, and scale the
-# scores and the probabilities.
-#
-# The chunked-prefill kernel (``flash_attention.paged_prefill_flash``)
-# still stages one frame per grid step through BlockSpecs and loops over
-# the heads inside; the helpers below, up to ``finish_carry``, are its.
-
-
-def frame_slot(j, valid_len, page: int):
-    """Page slot grid step ``j`` stages: steps past the sequence's last
-    live page re-point at that page, so the pipeline sees an unchanged
-    block index and issues no DMA for them (their compute is skipped by
-    the length mask anyway)."""
-    return jnp.minimum(j, jnp.maximum(valid_len - 1, 0) // page)
-
-
-def scale_rows(scales: jnp.ndarray) -> jnp.ndarray:
-    """(N, Hkv) frame scales -> the (N, 1, Hkv) view the kernels block."""
-    return scales.reshape(scales.shape[0], 1, scales.shape[1])
-
-
-def head_kv(k_ref, v_ref, ks_ref, vs_ref, h: int):
-    """KV head ``h`` of the staged frame as f32 (page, D) — dequantized
-    when the pool is quantized (the multiply fuses into the upcast)."""
-    k = k_ref[0, :, h, :].astype(jnp.float32)
-    v = v_ref[0, :, h, :].astype(jnp.float32)
-    if ks_ref is not None:
-        k = k * ks_ref[0, :, h:h + 1]
-        v = v * vs_ref[0, :, h:h + 1]
-    return k, v
-
-
-def init_carry(acc, m_s, l_s) -> None:
-    acc[...] = jnp.zeros_like(acc)
-    m_s[...] = jnp.full_like(m_s, NEG_INF)
-    l_s[...] = jnp.zeros_like(l_s)
-
-
-def online_softmax_step(q, k, v, mask, acc, m_s, l_s, i: int) -> None:
-    """Fold one page into carry row-group ``i``: ``q`` (R, D) f32
-    pre-scaled, ``k``/``v`` (page, D) f32, ``mask`` (R, page); ``acc``
-    (n, R, D) and ``m_s``/``l_s`` (n, R, lanes) are VMEM scratch."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (R, page)
-    s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_s[i, :, :1]
-    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_s[i, :, :1] = l_s[i, :, :1] * corr + p.sum(-1, keepdims=True)
-    m_s[i, :, :1] = m_new
-    acc[i] = acc[i] * corr + jax.lax.dot(p, v)
-
-
-def finish_carry(o_ref, acc, l_s, i: int) -> None:
-    o_ref[0, i] = (acc[i] / jnp.maximum(l_s[i, :, :1], 1e-30)) \
-        .astype(o_ref.dtype)
+# scores and the probabilities.  The chunked-prefill kernel
+# (``flash_attention.paged_prefill_flash``) stages blocks the same way,
+# a grid step per query block.
 
 
 # Token budget of one block: a block of P frames is gathered by one

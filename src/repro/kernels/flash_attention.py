@@ -12,6 +12,16 @@ SWA window) are skipped with ``pl.when`` — the skipped blocks never even
 issue their DMA on TPU (the index map still points at them, but Mosaic
 elides dead loads within revisited blocks; the FLOP savings are what
 matters for the roofline).
+
+Paged layout (``paged_prefill_flash``, chunked prefill): q is one prompt
+chunk per row at its own absolute offset, and K/V live in the
+``repro.paging`` pool.  The pool stays in HBM; a grid step serves one
+query block and loops over the KV blocks its queries can reach only,
+each block P frames the kernel gathers itself by DMA through the
+scalar-prefetched page table, double-buffered, as the paged decode
+kernel does (``decode_attention``).  Each block meets the grouped query
+heads of one KV head as one score tile, and only blocks on the causal
+diagonal, the row's written extent or the window's edge are masked.
 """
 
 from __future__ import annotations
@@ -25,10 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import (finish_carry, frame_slot,
-                                            head_kv, init_carry,
-                                            online_softmax_step,
-                                            resolve_interpret, scale_rows)
+from repro.kernels.decode_attention import (_BLOCK_TOKENS, _scale_width,
+                                            heads_major, resolve_interpret)
 
 __all__ = ["flash_attention", "paged_prefill_flash"]
 
@@ -139,60 +147,272 @@ def flash_attention(
 # ---------------------------------------------------------------------------
 # paged prefill: flash-attend prompt chunks against page-table-gathered KV
 # ---------------------------------------------------------------------------
+#
+# Blocking: a grid step per query block of ``bq`` tokens of one chunk row,
+# run in order.  The pool is an HBM operand, as in the paged decode kernel
+# (``decode_attention._paged_blocks_kernel``): the step loops over the KV
+# blocks its queries can reach only, each block P whole frames copied by
+# one DMA per frame (and stream) through the scalar-prefetched page table
+# into one slot of a double-buffered VMEM block, the next block's copies
+# (or the next step's first) in flight while this one is folded.  A block
+# is folded one KV head at a time as one (G*bq, P*page) score tile, the G
+# grouped query heads of that KV head stacked as rows; the softmax carry
+# of every head lives in VMEM scratch across the blocks.
+
+# Query tokens of one block, and the VMEM the blocks' buffers, the carry
+# and one head's working set may take (the compiler is given the budget
+# and the headroom above it).
+_Q_BLOCK = 128
+_PREFILL_VMEM = 24 << 20
+_VMEM_HEADROOM = 8 << 20
 
 
-def _paged_prefill_kernel(pt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
-                          *rest, scale: float, page: int, Hkv: int, G: int,
-                          window: int, bq: int, quant: bool = False):
-    # one grid step = one staged frame (every KV head of one page) against
-    # one query block of every head; the KV-head loop runs inside, each
-    # KV head serving its G grouped query heads
-    if quant:
-        ks_ref, vs_ref, o_ref, acc, m_s, l_s = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc, m_s, l_s = rest
-    b, iq, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    n_pg = pl.num_programs(2)
+def prefill_blocks(T: int, page: int, pages_per_seq: int, Hkv: int, G: int,
+                   D: int, itemsize: int, quant: bool) -> tuple:
+    """(bq, P): query tokens and frames of one block.  bq is at most
+    ``_Q_BLOCK`` and the chunk; P the most frames within the decode
+    kernel's ``_BLOCK_TOKENS`` and the row's table.  P, then bq, is
+    halved until the staged K/V (and scale) blocks, the q and output
+    blocks, the carry and one head's f32 working set fit
+    ``_PREFILL_VMEM``."""
+    bq = min(_Q_BLOCK, T)
+    P = max(1, min(_BLOCK_TOKENS // page, pages_per_seq))
 
-    @pl.when(j == 0)
+    def vmem(bq, P):
+        H, cols = Hkv * G, P * page
+        staged = 2 * 2 * cols * Hkv * D * itemsize      # K, V x 2 slots
+        if quant:
+            staged += 2 * 2 * P * Hkv * _scale_width(page, 1) * 4
+        io = 2 * 2 * H * bq * D * 4                     # q, out x 2 buffers
+        carry = H * bq * (D + 2 * _LANE) * 4
+        work = 3 * G * bq * cols * 4 + 2 * cols * D * 4
+        return staged + io + carry + work
+
+    while vmem(bq, P) > _PREFILL_VMEM and (P > 1 or bq > 8):
+        if P > 1:
+            P //= 2
+        else:
+            bq //= 2
+    return bq, P
+
+
+def _paged_prefill_kernel(pt_ref, off_ref, len_ref, q_ref, *refs,
+                          scale: float, page: int, P: int, Hkv: int, G: int,
+                          bq: int, window: int, quant: bool,
+                          head_major: bool):
+    """Grid ``(C, T // bq)``: step (c, i) attends query block i of chunk
+    row c (absolute positions ``offset[c] + i * bq`` on) to the row's KV
+    through position ``offset[c] + lengths[c] - 1``, causally (and within
+    ``window``).  It visits blocks ``b0 .. b1 - 1`` of the row's table,
+    ``b1 = cdiv(reach, P * page)`` with ``reach`` the first position its
+    queries cannot attend, ``b0`` the first block inside the window; a
+    query block past the row's length (all of a length-0 row) visits
+    none and writes zeros.  Frames past ``reach`` are never copied.
+
+    ``refs``: the HBM streams (K, V and, when ``quant``, their scale
+    rows), the output block, one VMEM buffer per stream, the carry
+    (``acc``, ``m``, ``l``: one slab per KV head), the DMA semaphores
+    and the SMEM slot of the block in flight across steps."""
+    n_streams = 4 if quant else 2
+    hbm, o_ref = refs[:n_streams], refs[n_streams]
+    bufs = refs[n_streams + 1:2 * n_streams + 1]
+    acc_ref, m_ref, l_ref, sems, slot_ref = refs[2 * n_streams + 1:]
+    c, i = pl.program_id(0), pl.program_id(1)
+    n_q = pl.num_programs(1)
+    has_next = c * n_q + i + 1 < pl.num_programs(0) * n_q
+    c_next = jnp.where(i + 1 < n_q, c, c + 1)
+    i_next = jnp.where(i + 1 < n_q, i + 1, 0)
+    D = q_ref.shape[-1]
+    bt = P * page                                   # tokens per block
+    dt = q_ref.dtype if quant else jnp.promote_types(q_ref.dtype,
+                                                     bufs[0].dtype)
+
+    def span(row, qb):
+        """Blocks ``b0 .. b1 - 1`` step (row, qb) visits and the frames
+        of the row up to its reach (none past the row's length)."""
+        first_q = off_ref[row] + qb * bq
+        reach = jnp.minimum(off_ref[row] + len_ref[row], first_q + bq)
+        live = qb * bq < len_ref[row]
+        b1 = jnp.where(live, (reach + bt - 1) // bt, 0)
+        b0 = (jnp.maximum(first_q - window + 1, 0) // bt if window
+              else jnp.int32(0))
+        return (jnp.minimum(b0, b1), b1,
+                jnp.where(live, (reach + page - 1) // page, 0))
+
+    def transfer(row, blk, slot, n_fr, start: bool):
+        """Start (or wait for) the DMAs of block ``blk`` of ``row`` into
+        ``slot``: one per frame below ``n_fr`` and stream."""
+        def one(p, carry):
+            frame = pt_ref[row, blk * P + p]
+            for s in range(n_streams):
+                cp = pltpu.make_async_copy(hbm[s].at[frame],
+                                           bufs[s].at[slot, p],
+                                           sems.at[s, slot])
+                if start:
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_fr - blk * P, 0, P), one, 0)
+
+    def start_next(slot):
+        b0, _, n_fr = span(c_next, i_next)
+        transfer(c_next, b0, slot, n_fr, True)
+
+    @pl.when((c == 0) & (i == 0))
     def _():
-        init_carry(acc, m_s, l_s)
+        # frames never copied keep what the buffer held: make that
+        # finite, since a masked column still meets V in the p @ v dot
+        for buf in bufs[1:]:
+            buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+        b0, _, n_fr = span(0, 0)
+        transfer(0, b0, 0, n_fr, True)
 
-    offset = off_ref[b]
-    kv_valid = offset + len_ref[b]
-    first_q = offset + iq * bq
-    last_q = first_q + bq - 1
-    first_kv = j * page
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    slot0 = slot_ref[0]
+    b0, b1, n_fr = span(c, i)
 
-    # block liveness: this frame holds no position the row's queries may
-    # attend (outside the causal wedge / SWA window, or past the row's
-    # written extent) -> skip the whole tile
-    live = (first_kv < kv_valid) & (first_kv <= last_q)
-    if window:
-        live &= (first_kv + page - 1) > first_q - window
-
-    @pl.when(live)
+    @pl.when((b1 == b0) & has_next)
     def _():
-        q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, (bq, page), 0)
-        kv_pos = first_kv + jax.lax.broadcasted_iota(jnp.int32, (bq, page), 1)
-        mask = (q_pos >= kv_pos) & (kv_pos < kv_valid)
-        if window:
-            mask &= kv_pos > q_pos - window
+        start_next(slot0)
+
+    first_q = off_ref[c] + i * bq
+    kv_valid = off_ref[c] + len_ref[c]
+    q_pos = first_q + jax.lax.broadcasted_iota(jnp.int32, (G * bq, 1), 0) % bq
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+
+    def head(s, slot, h):
+        """KV head ``h`` of stream ``s``'s block in ``slot``: (P*page, D)."""
+        x = bufs[s][slot, :, h] if head_major else bufs[s][slot, :, :, h]
+        return x.astype(dt if s == 0 else jnp.float32).reshape(bt, D)
+
+    def scale_rows(s, slot):
+        """Stream ``s``'s per-(frame, head) scales as (Hkv, P*page)."""
+        return jnp.concatenate([bufs[s][slot, p][:, :page] for p in range(P)],
+                               axis=-1)
+
+    def fold(blk, slot, masked: bool):
+        if masked:
+            kv = blk * bt + col
+            mask = (kv <= q_pos) & (kv < kv_valid)
+            if window:
+                mask &= kv > q_pos - window
+        if quant:
+            ks, vs = scale_rows(2, slot) * scale, scale_rows(3, slot)
         for h in range(Hkv):
-            k, v = head_kv(k_ref, v_ref, ks_ref, vs_ref, h)
-            for g in range(G):
-                hq = h * G + g
-                q = q_ref[0, hq].astype(jnp.float32) * scale     # (bq, D)
-                online_softmax_step(q, k, v, mask, acc, m_s, l_s, hq)
+            q = q_ref[0, h * G:(h + 1) * G].reshape(G * bq, D).astype(dt)
+            s = jax.lax.dot_general(q, head(0, slot, h),
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * (ks[h:h + 1] if quant else scale)
+            if masked:
+                s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[h, :, :1]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h, :, :1] = l_ref[h, :, :1] * corr + p.sum(-1, keepdims=True)
+            m_ref[h, :, :1] = m_new
+            if quant:
+                p = p * vs[h:h + 1]
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot(p, head(1, slot, h))
 
-    @pl.when(j == n_pg - 1)
-    def _():
-        for hq in range(Hkv * G):
-            finish_carry(o_ref, acc, l_s, hq)
+    def body(blk, slot):
+        nxt = 1 - slot
+
+        @pl.when(blk + 1 < b1)
+        def _():
+            transfer(c, blk + 1, nxt, n_fr, True)
+
+        @pl.when((blk + 1 == b1) & has_next)
+        def _():
+            start_next(nxt)
+
+        transfer(c, blk, slot, n_fr, False)
+        # only a block that crosses the causal diagonal or the window's
+        # edge takes a mask (one past the row's written extent crosses
+        # the diagonal: the block's first query lies inside the extent)
+        last = blk * bt + bt - 1
+        edge = last > first_q
+        if window:
+            edge |= blk * bt <= first_q + bq - 1 - window
+
+        @pl.when(edge)
+        def _():
+            fold(blk, slot, True)
+
+        @pl.when(jnp.logical_not(edge))
+        def _():
+            fold(blk, slot, False)
+
+        return nxt
+
+    slot_ref[0] = jax.lax.fori_loop(b0, b1, body, slot0)
+    for h in range(Hkv):
+        out = acc_ref[h] / jnp.maximum(l_ref[h, :, :1], 1e-30)
+        o_ref[0, h * G:(h + 1) * G] = out.reshape(G, bq, D).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "bq", "interpret"))
+def _paged_prefill(q, k_pages, v_pages, page_rows, offset, lengths,
+                   k_scales, v_scales, *, window: int, bq: int, P: int,
+                   interpret: bool):
+    """pallas_call over ``q`` (C, H, T, D), T a multiple of ``bq``."""
+    C, H, T, D = q.shape
+    N, page, Hkv, _ = k_pages.shape
+    quant = k_scales is not None
+    head_major = heads_major(Hkv, k_pages.dtype.itemsize)
+    if head_major:
+        # the pool's own layout there: the swap costs no copy
+        k_pages, v_pages = (jnp.swapaxes(a, 1, 2) for a in (k_pages, v_pages))
+    operands = [k_pages, v_pages]
+    scratch = [pltpu.VMEM((2, P) + k_pages.shape[1:], k_pages.dtype),
+               pltpu.VMEM((2, P) + v_pages.shape[1:], v_pages.dtype)]
+    if quant:
+        # each frame's scales as one row per KV head, spread over the
+        # frame's tokens and padded to whole lanes
+        width = _scale_width(page, 1)
+        operands += [jnp.pad(jnp.broadcast_to(s[:, :, None], (N, Hkv, page)),
+                             ((0, 0), (0, 0), (0, width - page)))
+                     for s in (k_scales, v_scales)]
+        scratch += [pltpu.VMEM((2, P, Hkv, width), jnp.float32)] * 2
+    n_streams = len(operands)
+    G = H // Hkv
+    scratch += [pltpu.VMEM((Hkv, G * bq, D), jnp.float32),
+                pltpu.VMEM((Hkv, G * bq, _LANE), jnp.float32),
+                pltpu.VMEM((Hkv, G * bq, _LANE), jnp.float32),
+                pltpu.SemaphoreType.DMA((n_streams, 2)),
+                pltpu.SMEM((1,), jnp.int32)]
+    kernel = functools.partial(_paged_prefill_kernel,
+                               scale=1.0 / math.sqrt(D), page=page, P=P,
+                               Hkv=Hkv, G=G, bq=bq, window=window,
+                               quant=quant, head_major=head_major)
+    q_spec = pl.BlockSpec((1, H, bq, D),
+                          lambda c, i, pt, off, ln: (c, 0, i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(C, T // bq),
+        in_specs=[q_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * n_streams,
+        out_specs=q_spec,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # steps run in order: each starts the next one's first block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM + _VMEM_HEADROOM),
+        interpret=interpret,
+    )(page_rows.astype(jnp.int32), offset.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, *operands)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_prefill_flash(
     q: jnp.ndarray,            # (C, H, T, D) — one prompt chunk per row
     k_pages: jnp.ndarray,      # (N, page, Hkv, D) — the device page pool
@@ -202,82 +422,37 @@ def paged_prefill_flash(
     lengths: jnp.ndarray,      # (C,) int32 valid tokens in each chunk row
     *,
     window: int = 0,
-    bq: int = 128,
     k_scales: Optional[jnp.ndarray] = None,   # (N, Hkv) f32 — quantized pool
     v_scales: Optional[jnp.ndarray] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Chunked-prefill flash attention reading KV through the page table.
 
-    The paged-KV variant of :func:`flash_attention`: each grid row is one
-    admitting sequence's prompt chunk, and the KV grid dimension streams
-    that sequence's pool *frames* through VMEM — the page-table row is a
-    scalar-prefetch operand whose dereference picks the frame each step
-    DMAs, exactly like ``decode_attention.paged_decode_attention`` but
-    with a (bq, page) score tile per query head instead of one query
-    token.  Per-row ``offset``/``lengths`` (also scalar-prefetched)
-    shift the causal wedge to each row's absolute position, so one call
+    The paged-KV variant of :func:`flash_attention`: each row is one
+    admitting sequence's prompt chunk at its own absolute ``offset``
+    (scalar-prefetched with ``lengths`` and the page table), so one call
     serves chunk rows of different sequences at different prefill
-    depths.  Frames past the last one a query block may attend re-use
-    that frame's block index (no DMA) and are skipped by block liveness.
+    depths.  A query block of ``bq`` tokens visits only the KV blocks
+    its queries can reach, P frames a block gathered from the pool by
+    DMA through the page table, double-buffered; each block meets the G
+    query heads of one KV head as one (G*bq, P*page) score tile, and
+    only blocks that cross the causal diagonal, the row's written extent
+    or the window's edge are masked.  bq and P follow from the shapes
+    (:func:`prefill_blocks`).  A query block that holds none of its
+    row's tokens (every block of a length-0 row) copies nothing and
+    returns zeros; callers read only positions below ``lengths``.
     """
     interpret = resolve_interpret(interpret)
     C, H, T, D = q.shape
-    N, page, Hkv, _ = k_pages.shape
-    pages_per_seq = page_rows.shape[1]
-    bq = min(bq, T)
+    _, page, Hkv, _ = k_pages.shape
+    bq, P = prefill_blocks(T, page, page_rows.shape[1], Hkv, H // Hkv, D,
+                           k_pages.dtype.itemsize, k_scales is not None)
     pad_t = (-T) % bq
     if pad_t:
         # pad the chunk axis up to a block multiple; padded queries
-        # produce don't-care rows that are sliced off below (callers
-        # only read positions below each row's valid length anyway)
+        # produce don't-care rows that are sliced off below
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
-        T = T + pad_t
-
-    def frame(c, i, j, pt, off, ln):
-        # the last position query block i may attend: its own last query,
-        # or the row's written extent if that ends first
-        reach = jnp.minimum(off[c] + ln[c], off[c] + (i + 1) * bq)
-        return pt[c, frame_slot(j, reach, page)]
-
-    quant = k_scales is not None
-    kernel = functools.partial(_paged_prefill_kernel,
-                               scale=1.0 / math.sqrt(D), page=page, Hkv=Hkv,
-                               G=H // Hkv, window=window, bq=bq, quant=quant)
-    in_specs = [
-        pl.BlockSpec((1, H, bq, D),
-                     lambda c, i, j, pt, off, ln: (c, 0, i, 0)),
-        pl.BlockSpec((1, page, Hkv, D),
-                     lambda c, i, j, pt, off, ln:
-                     (frame(c, i, j, pt, off, ln), 0, 0, 0)),
-        pl.BlockSpec((1, page, Hkv, D),
-                     lambda c, i, j, pt, off, ln:
-                     (frame(c, i, j, pt, off, ln), 0, 0, 0)),
-    ]
-    operands = [q, k_pages, v_pages]
-    if quant:
-        spec = pl.BlockSpec((1, 1, Hkv),
-                            lambda c, i, j, pt, off, ln:
-                            (frame(c, i, j, pt, off, ln), 0, 0))
-        in_specs += [spec, spec]
-        operands += [scale_rows(k_scales), scale_rows(v_scales)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(C, T // bq, pages_per_seq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, bq, D),
-                               lambda c, i, j, pt, off, ln: (c, 0, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, bq, D), jnp.float32),
-            pltpu.VMEM((H, bq, _LANE), jnp.float32),
-            pltpu.VMEM((H, bq, _LANE), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(page_rows.astype(jnp.int32), offset.astype(jnp.int32),
-      lengths.astype(jnp.int32), *operands)
-    return out[:, :, :T - pad_t] if pad_t else out
+    out = _paged_prefill(q, k_pages, v_pages, page_rows, offset, lengths,
+                         k_scales, v_scales, window=window, bq=bq, P=P,
+                         interpret=interpret)
+    return out[:, :, :T] if pad_t else out

@@ -205,8 +205,11 @@ def paged_prefill_attention(q, k_pages, v_pages, page_rows, offset, lengths,
     ``chunked_attention`` expressions dense prefill uses (per-row
     ``q_offset`` shifts the causal wedge), which is what keeps a chunked
     prefill's generated tokens equal to an uninterrupted dense prefill's.
-    The pallas/interpret path is the scalar-prefetch flash kernel
-    (``flash_attention.paged_prefill_flash``).
+    The pallas/interpret path is the blocked flash kernel
+    (``flash_attention.paged_prefill_flash``): each query block gathers
+    only the KV blocks it can reach, P frames a block by DMA through the
+    page table, and reads a quantized pool with its scales fused.
+    Positions past a row's length are don't-care in both paths.
     """
     impl = resolve_impl(impl)
     if impl == "xla":

@@ -8,11 +8,11 @@ the serving path — decode, speculative verify, chunked prefill — in
 bf16 and int8-pool form at phi4-mini-3.8b's published widths (24 query
 heads, 8 KV heads, head_dim 128, page 16) for a ``v5e:2x2`` topology
 described, not attached, and check that a Mosaic kernel is in the
-program: at max_len 4096 (256 pages a row) and, for decode and verify, at
-4000 (250 pages, which their blocks of frames do not divide, so a row's
-last block is ragged) and with the 2 (decode also 1) KV heads a device
-holds when the pool is sharded over 4 or 8 devices (where they are fewer
-than the pool dtype's row tile, XLA keeps each frame head-major).  No
+program: at max_len 4096 (256 pages a row), at 4000 (250 pages, which
+their blocks of frames do not divide, so a row's last block is ragged)
+and with the 2 (decode and prefill also 1) KV heads a device holds when
+the pool is sharded over 4 or 8 devices (where they are fewer than the
+pool dtype's row tile, XLA keeps each frame head-major).  No
 operation outside the kernel may write an array the size of the pool:
 the kernels read the pool as it lies, with no copy, pad or relayout of
 it per call.  Nothing runs; no chip is needed.
@@ -103,10 +103,12 @@ def _lower(kernel, pool_dtype, sharding, pages_per_seq, kv_heads):
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("kernel,max_len,kv_heads", [
     ("decode", 4096, HKV), ("verify", 4096, HKV), ("prefill", 4096, HKV),
-    ("decode", 4000, HKV), ("verify", 4000, HKV),
-    ("decode", 4096, 2), ("decode", 4096, 1), ("verify", 4096, 2)],
+    ("decode", 4000, HKV), ("verify", 4000, HKV), ("prefill", 4000, HKV),
+    ("decode", 4096, 2), ("decode", 4096, 1), ("verify", 4096, 2),
+    ("prefill", 4096, 2), ("prefill", 4096, 1)],
     ids=["decode", "verify", "prefill", "decode-4000", "verify-4000",
-         "decode-2kv", "decode-1kv", "verify-2kv"])
+         "prefill-4000", "decode-2kv", "decode-1kv", "verify-2kv",
+         "prefill-2kv", "prefill-1kv"])
 def test_paged_kernel_compiles_for_v5e(one_chip, kernel, max_len, kv_heads,
                                        pool_dtype):
     compiled = _lower(kernel, pool_dtype, one_chip, max_len // PAGE,
