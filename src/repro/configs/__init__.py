@@ -9,8 +9,7 @@ from __future__ import annotations
 import importlib
 from typing import Dict, List
 
-from repro.configs.base import (ModelConfig, ShapeConfig, MeshConfig,
-                                TrainConfig, SHAPES)
+from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 
 _ARCH_MODULES: Dict[str, str] = {
     "rwkv6-7b": "rwkv6_7b",
@@ -42,21 +41,7 @@ def get_smoke(arch_id: str) -> ModelConfig:
     return _module(arch_id).SMOKE
 
 
-def get_shape(name: str) -> ShapeConfig:
-    for s in SHAPES:
-        if s.name == name:
-            return s
-    raise KeyError(f"unknown shape {name!r}; known: {[s.name for s in SHAPES]}")
-
-
-def cell_is_runnable(cfg: ModelConfig, shape: ShapeConfig) -> bool:
-    """long_500k only runs for sub-quadratic archs (SSM/hybrid/SWA)."""
-    if shape.name == "long_500k" and not cfg.is_subquadratic:
-        return False
-    return True
-
-
 __all__ = [
-    "ModelConfig", "ShapeConfig", "MeshConfig", "TrainConfig", "SHAPES",
-    "ARCH_IDS", "get_config", "get_smoke", "get_shape", "cell_is_runnable",
+    "ModelConfig", "ShapeConfig", "TrainConfig",
+    "ARCH_IDS", "get_config", "get_smoke",
 ]

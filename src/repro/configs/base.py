@@ -1,4 +1,4 @@
-"""Config dataclasses: model architecture, input shapes, mesh, training.
+"""Config dataclasses: model architecture, input shapes, training.
 
 Frozen dataclasses so they hash into jit static arguments.  Every assigned
 architecture in ``repro/configs/<id>.py`` instantiates :class:`ModelConfig`
@@ -9,11 +9,10 @@ same family for CPU tests.
 from __future__ import annotations
 
 import dataclasses
-import math
 from dataclasses import dataclass, field, replace
 from typing import Optional, Tuple
 
-__all__ = ["ModelConfig", "ShapeConfig", "MeshConfig", "TrainConfig", "SHAPES"]
+__all__ = ["ModelConfig", "ShapeConfig", "TrainConfig"]
 
 
 def _round_up(x: int, m: int) -> int:
@@ -85,11 +84,6 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // max(1, self.num_kv_heads)
-
-    @property
-    def is_subquadratic(self) -> bool:
-        """May run long_500k: SSM/linear/hybrid/SWA families."""
-        return self.family in ("ssm", "hybrid") or self.attention == "swa"
 
     @property
     def has_decoder(self) -> bool:
@@ -167,7 +161,7 @@ class ModelConfig:
 
 @dataclass(frozen=True)
 class ShapeConfig:
-    """One input-shape suite cell."""
+    """The input shape a step program is built for."""
 
     name: str
     seq_len: int
@@ -177,34 +171,6 @@ class ShapeConfig:
     @property
     def tokens(self) -> int:
         return self.seq_len * self.global_batch
-
-
-SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4_096, 256, "train"),
-    ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    ShapeConfig("long_500k", 524_288, 1, "decode"),
-)
-
-
-@dataclass(frozen=True)
-class MeshConfig:
-    """Logical mesh description (see launch/mesh.py)."""
-
-    shape: Tuple[int, ...] = (16, 16)
-    axes: Tuple[str, ...] = ("data", "model")
-
-    @property
-    def n_devices(self) -> int:
-        return math.prod(self.shape)
-
-    @property
-    def data_axes(self) -> Tuple[str, ...]:
-        return tuple(a for a in self.axes if a in ("pod", "data"))
-
-    @property
-    def axis_size(self):
-        return dict(zip(self.axes, self.shape))
 
 
 @dataclass(frozen=True)

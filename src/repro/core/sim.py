@@ -13,8 +13,8 @@ This module reproduces that claim with a small discrete-event model that
 is deliberately faithful to the paper's resource vocabulary (ROB, MSHR,
 outstanding slots, granularity), plus closed-form Little's-law bounds so
 tests can check the DES against analysis.  It is pure Python/NumPy —
-deterministic, seedable, CPU-fast — and drives
-``benchmarks/bench_sim.py`` and EXPERIMENTS.md §Paper-claims.
+deterministic, seedable, CPU-fast — and a tool for reasoning about the
+paper's claim, never the source of a speed number.
 
 Latency distributions model the paper's tiers: local DRAM ~100-200 ns,
 disaggregated pool 300 ns – 2 µs, NVM / remote-node tail up to 10 µs.

@@ -8,9 +8,9 @@ and size it per workload.  On TPU all of VMEM is software-managed, so the
   * prefetch buffers (the "SPM" share — tiles in flight via async DMA).
 
 :class:`SPMPlan` turns (VMEM budget, tile byte-sizes, desired pipeline
-depth) into concrete block shapes + buffer counts that kernels and the
-dry-run use.  It is deliberately analytical — the same arithmetic a kernel
-author does on a napkin — so tests can assert its invariants.
+depth) into concrete block shapes + buffer counts that kernels use.  It
+is deliberately analytical — the same arithmetic a kernel author does
+on a napkin — so tests can assert its invariants.
 """
 
 from __future__ import annotations

@@ -12,8 +12,7 @@ Three modules, consumed by ``repro.models`` (lazily, per call site),
     optimizer (ZeRO-1) and batch pytrees over the (pod, data, model)
     mesh axes built by :mod:`repro.launch.mesh`,
   * :mod:`repro.dist.steps` — jitted, donated, mesh-sharded train /
-    prefill / serve step factories plus abstract-input builders for the
-    compile-only dry-run.
+    prefill / serve step factories plus abstract-input builders.
 
 The AMU thesis at system scale: latency (far memory there, inter-chip
 collectives here) is hidden by keeping many independent units of work in

@@ -15,7 +15,7 @@ the model layers at trace time.  Two named instances:
 
 Everything degrades to a no-op when no mesh is active or when a shape
 does not divide the mesh axis — single-device tests exercise the exact
-same code path as the 512-way dry-run.
+same code path as a sharded mesh.
 
 Layout selection for attention (:func:`attn_plan`):
 

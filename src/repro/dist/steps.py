@@ -4,7 +4,8 @@ Each ``make_*_step`` returns ``(fn, specs)``:
 
   * ``fn`` — a callable that enters the mesh context and invokes the
     underlying ``jax.jit``; it also exposes ``.lower(*abstract_args)``
-    so the compile-only dry-run can lower cells without allocating,
+    so a step can be compiled from abstract arguments without
+    allocating,
   * ``specs`` — the PartitionSpec trees (``params`` / ``opt`` /
     ``batch``) the caller uses to place inputs.
 
@@ -13,14 +14,13 @@ Sharding is enforced *inside* the step via ``with_sharding_constraint``
 train outputs carry explicit ``out_shardings`` so donation lines up and
 updated parameters stay TP/ZeRO-sharded across steps.
 
-``abstract_params`` / ``abstract_opt_state`` / ``train_inputs`` /
-``decode_inputs`` build ``ShapeDtypeStruct`` pytrees — nothing is
-allocated — for spec construction and dry-run lowering.
+``abstract_params`` / ``abstract_opt_state`` build ``ShapeDtypeStruct``
+pytrees — nothing is allocated — for spec construction.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +35,7 @@ from repro.optim.adamw import adamw_init, adamw_update
 __all__ = [
     "make_train_step", "make_prefill_step", "make_serve_step",
     "make_mixed_step", "abstract_params", "abstract_opt_state",
-    "init_params_sharded", "train_inputs", "decode_inputs",
-    "paged_cache_specs",
+    "init_params_sharded", "paged_cache_specs",
 ]
 
 
@@ -59,25 +58,6 @@ def init_params_sharded(cfg: ModelConfig, mesh, key):
 
 def abstract_opt_state(cfg: ModelConfig):
     return jax.eval_shape(adamw_init, abstract_params(cfg))
-
-
-def train_inputs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
-    B, S = shape.global_batch, shape.seq_len
-    batch = {
-        "tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
-        "labels": jax.ShapeDtypeStruct((B, S), jnp.int32),
-    }
-    if cfg.family == "encdec":
-        batch["src_embeds"] = jax.ShapeDtypeStruct((B, S, cfg.d_model),
-                                                   jnp.float32)
-    return batch
-
-
-def decode_inputs(cfg: ModelConfig, shape: ShapeConfig):
-    B, S = shape.global_batch, shape.seq_len
-    cache = jax.eval_shape(lambda: model_mod.init_cache(cfg, B, S))
-    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32)
-    return cache, tokens
 
 
 def paged_cache_specs(mesh, cfg: ModelConfig) -> Dict[str, P]:

@@ -3,8 +3,8 @@
 ``impl``:
   * ``"pallas"``    — compiled Pallas kernel (TPU target),
   * ``"interpret"`` — Pallas kernel body interpreted on CPU (correctness),
-  * ``"xla"``       — the pure-jnp path (oracle-equivalent, what the
-    dry-run lowers, since Mosaic cannot target the CPU backend),
+  * ``"xla"``       — the pure-jnp path (oracle-equivalent, what a CPU
+    backend lowers, since Mosaic cannot target it),
   * ``"auto"``      — pallas on TPU, xla elsewhere.
 
 Model code calls these wrappers; tests sweep ``interpret`` vs ``xla``.

@@ -5,8 +5,8 @@ Two execution paths exist for the core softmax-attention compute:
   * :func:`repro.kernels.ops.flash_attention` — the Pallas AMU kernel
     (TPU target, interpret-validated),
   * the chunked online-softmax implementation here (`_chunked_attention`)
-    — pure jnp, O(S·C) peak memory, used for XLA lowering in the dry-run
-    and as the CPU execution path.  Both agree with ``kernels/ref.py``.
+    — pure jnp, O(S·C) peak memory, the XLA lowering and the CPU
+    execution path.  Both agree with ``kernels/ref.py``.
 
 The chunk loop is a ``lax.scan`` over KV blocks: exactly the AMU stream
 pattern (fetch KV chunk → accumulate online softmax → next), so what the

@@ -17,11 +17,10 @@ one-to-one onto the source paper's architecture:
     prefix sharing: full prompt pages interned by rolling token-id
     hash, mapped into new requests' page tables as refcounted/COW
     shared frames (device hit) or LATENCY far-tier fetches (far hit),
-  * :mod:`repro.paging.sim` — deterministic policy simulations feeding
-    the ``paged_kv_sweep`` (pager vs blocking fetch),
-    ``mixed_batch_sweep`` (chunked continuous batching vs serial dense
-    prefill) and ``prefix_reuse_sweep`` (prefix sharing vs recompute)
-    benchmarks.
+  * :mod:`repro.paging.sim` — deterministic policy simulations on a
+    virtual clock (pager vs blocking fetch, chunked continuous batching
+    vs serial dense prefill, SLO-aware vs watermark scheduling), used
+    as policy tests.
 
 The serving engine (:mod:`repro.serve.engine`) consumes all of it: both
 decode *and* chunked prefill compute directly on the pool layout, so

@@ -19,7 +19,8 @@ Here the same loop shape schedules *sequences* instead of cache lines:
 The loop itself is deliberately tiny and deterministic: a FIFO event
 queue drained to empty each iteration, with handlers registered per
 event kind.  Both the serving engine (`repro.serve.engine`) and the
-``paged_kv_sweep`` benchmark drive their scheduling through it.
+virtual-clock simulations (`repro.paging.sim`) drive their scheduling
+through it.
 """
 
 from __future__ import annotations

@@ -13,7 +13,7 @@ policies the paper contrasts:
 Everything runs through the real :class:`~repro.paging.Pager` /
 :class:`~repro.paging.PagePool` / :class:`~repro.paging.EventLoop`
 against a simulated-latency AMU, so the numbers are deterministic and
-the benchmark doubles as an integration test of the subsystem.
+each simulation doubles as an integration test of the subsystem.
 """
 
 from __future__ import annotations
@@ -25,29 +25,8 @@ from repro.paging.events import EventKind, EventLoop
 from repro.paging.page_table import PagePool, PageState, PageTable
 from repro.paging.pager import Pager
 
-__all__ = ["kv_page_nbytes", "simulate_paged_serving",
-           "simulate_mixed_batching", "simulate_prefix_reuse",
-           "simulate_slo_schedule", "simulate_disagg",
-           "simulate_spec_decode"]
-
-
-def kv_page_nbytes(page_size: int = 16, n_layers: int = 32,
-                   kv_heads: int = 8, head_dim: int = 16, *,
-                   itemsize: int = 2,
-                   scale_bytes_per_head: int = 0) -> int:
-    """Bytes one paged KV frame moves over the AMU — the transfer-cost
-    model every page-granularity sim sweep shares.
-
-    Mirrors the engine's ``page_nbytes`` accounting exactly: K and V
-    payloads at the pool dtype (``2 * L * page * Hkv * D * itemsize``)
-    plus, for a quantized pool, the per-(layer, head) scale pair that
-    rides every transfer (``2 * L * Hkv * scale_bytes_per_head``).  The
-    defaults reproduce the historical 256 KiB page of
-    :func:`simulate_paged_serving`; a quantized sweep passes
-    ``itemsize=1, scale_bytes_per_head=4`` for int8/fp8 frames."""
-    payload = 2 * n_layers * page_size * kv_heads * head_dim * itemsize
-    scales = 2 * n_layers * kv_heads * scale_bytes_per_head
-    return int(payload + scales)
+__all__ = ["simulate_paged_serving", "simulate_mixed_batching",
+           "simulate_slo_schedule"]
 
 
 def simulate_paged_serving(
@@ -181,9 +160,9 @@ def simulate_mixed_batching(
     ``oversubscription`` here is *request* oversubscription — offered
     load versus slot capacity: ``oversubscription * max_batch * 4``
     requests arrive at t=0 against ``max_batch`` decode slots (the
-    page-pool oversubscription axis is ``paged_kv_sweep``'s job; this
-    bench isolates the admission bubble, so the pool holds every slot's
-    working set with watermark headroom).  Two admission policies over
+    page-pool oversubscription axis is :func:`simulate_paged_serving`'s
+    job; this one isolates the admission bubble, so the pool holds every
+    slot's working set with watermark headroom).  Two admission policies over
     one virtual clock:
 
     * **serial dense prefill** — the pre-chunking engine: admitting a
@@ -305,423 +284,6 @@ def simulate_mixed_batching(
                                / dense["decode_tok_per_s"]),
         "tpot_dense_us": dense["tpot_mean"] * 1e6,
         "tpot_mixed_us": mixed["tpot_mean"] * 1e6,
-    }
-
-
-def simulate_spec_decode(
-    oversubscription: float,
-    *,
-    traffic: str = "repetitive",
-    max_batch: int = 4,
-    prompt_tokens: int = 64,
-    new_tokens: int = 48,
-    speculate_k: int = 4,
-    ngram: int = 3,
-    period: int = 8,
-    vocab: int = 512,
-    seed: int = 0,
-    t_decode_step: float = 20e-6,
-    t_prefill_token: float = 1.5e-6,
-    c_verify: float = 0.15,
-) -> Dict[str, float]:
-    """Self-speculative verify-K decode vs single-step, deterministic.
-
-    A burst of ``oversubscription * max_batch * 4`` requests is served
-    on ``max_batch`` decode slots over a virtual clock; each request's
-    *true* token stream is synthetic and known up front, so greedy
-    acceptance is exact prefix matching against it — the same algebra
-    the engine's verify step runs against argmax logits.  Two traffic
-    shapes:
-
-    * ``"repetitive"`` — each stream cycles a per-request random
-      ``period``-gram, the prompt-lookup proposer's best case (code,
-      templated text); trailing n-grams recur, so drafts are nearly
-      always the true continuation,
-    * ``"adversarial"`` — i.i.d. uniform random tokens; with a large
-      vocabulary the trailing n-gram essentially never recurs, so the
-      proposer rarely fires and almost nothing it drafts survives.
-
-    Drafting uses the REAL :class:`~repro.serve.speculate.NgramProposer`
-    over each request's prompt + generated history, not a model of it.
-    A speculative step's cost scales with the widest draft actually
-    batched that step — the verify matmul's extra query rows —
-    ``t_decode_step * (1 + c_verify * K_step)``, and each slot advances
-    ``1 + accepted``; the single-step baseline pays ``t_decode_step``
-    per token.  Pages are not the constraint here (that is
-    ``paged_kv_sweep``); admission is slot-bound with serial prefill on
-    both sides, so the ratio isolates verify-K compression.
-
-    Returns tokens/s for both policies, the throughput speedup, and
-    mean accepted-K per drafting slot (the acceptance telemetry the
-    engine reports from its ``spec_*`` tracks).
-    """
-    import random as _random
-
-    from repro.serve.speculate import NgramProposer
-
-    if traffic not in ("repetitive", "adversarial"):
-        raise ValueError(f"unknown traffic shape {traffic!r}")
-    n_seqs = max(1, int(round(oversubscription * max_batch * 4)))
-    total_len = prompt_tokens + new_tokens
-    streams = []
-    for s in range(n_seqs):
-        rng = _random.Random((seed, traffic, s))
-        if traffic == "repetitive":
-            pattern = [rng.randrange(vocab) for _ in range(period)]
-            streams.append([pattern[i % period] for i in range(total_len)])
-        else:
-            streams.append([rng.randrange(vocab) for _ in range(total_len)])
-
-    def run(speculative: bool) -> Dict[str, float]:
-        now = 0.0
-        queue = list(range(n_seqs))
-        running: Dict[int, int] = {}        # seq -> tokens generated
-        proposer = NgramProposer(n=ngram, k=speculate_k)
-        drafted = accepted = spec_steps = n_drafts = 0
-        done = 0
-        while done < n_seqs:
-            while queue and len(running) < max_batch:
-                seq = queue.pop(0)
-                now += prompt_tokens * t_prefill_token  # serial prefill
-                running[seq] = 0
-            k_step = 0
-            advances: Dict[int, int] = {}
-            for seq in sorted(running):
-                gen = running[seq]
-                budget = new_tokens - gen - 1
-                adv = 1
-                if speculative and budget > 0:
-                    hist = streams[seq][:prompt_tokens + gen]
-                    draft = proposer.propose(seq, hist)[:budget]
-                    if draft:
-                        true_tail = streams[seq][prompt_tokens + gen:]
-                        acc = 0
-                        while acc < len(draft) \
-                                and draft[acc] == true_tail[acc]:
-                            acc += 1
-                        drafted += len(draft)
-                        accepted += acc
-                        n_drafts += 1
-                        k_step = max(k_step, len(draft))
-                        adv = 1 + acc
-                advances[seq] = adv
-            now += t_decode_step * (1.0 + c_verify * k_step)
-            if k_step:
-                spec_steps += 1
-            for seq, adv in advances.items():
-                running[seq] += adv
-                if running[seq] >= new_tokens:
-                    del running[seq]
-                    proposer.drop(seq)
-                    done += 1
-        return {
-            "wall": now,
-            "tok_per_s": n_seqs * new_tokens / now,
-            "drafted": drafted,
-            "accepted": accepted,
-            "spec_steps": spec_steps,
-            "n_drafts": n_drafts,
-        }
-
-    plain = run(speculative=False)
-    spec = run(speculative=True)
-    return {
-        "oversubscription": oversubscription,
-        "n_seqs": float(n_seqs),
-        "tok_per_s_plain": plain["tok_per_s"],
-        "tok_per_s_spec": spec["tok_per_s"],
-        "throughput_speedup": spec["tok_per_s"] / plain["tok_per_s"],
-        "drafted": float(spec["drafted"]),
-        "accepted": float(spec["accepted"]),
-        "mean_accepted_k": (spec["accepted"] / spec["n_drafts"]
-                            if spec["n_drafts"] else 0.0),
-        "acceptance_rate": (spec["accepted"] / spec["drafted"]
-                            if spec["drafted"] else 0.0),
-    }
-
-
-def simulate_disagg(
-    oversubscription: float,
-    *,
-    max_batch: int = 4,
-    prompt_tokens: int = 128,
-    new_tokens: int = 32,
-    page_size: int = 16,
-    chunk_tokens: int = 8,
-    chunk_slots: int = 2,
-    low_watermark: int = 1,
-    t_decode_step: float = 20e-6,
-    t_prefill_token: float = 1.5e-6,
-    page_bytes: int = 256 << 10,
-    base_latency: float = 10e-6,
-    bandwidth: float = 10e9,
-) -> Dict[str, float]:
-    """Two-pool disaggregated prefill/decode vs fused mixed batching, at
-    matched device counts, deterministic.
-
-    Both sides get **two devices** and the same offered load
-    (``oversubscription * max_batch * 4`` requests *per device*, all at
-    t=0):
-
-    * **fused** — two independent ``make_mixed_step`` engines, each
-      taking half the traffic: every step fuses one decode token per
-      running slot with up to ``chunk_slots`` prompt chunks, so chunk
-      FLOPs stretch decode steps (``max(t_decode_step, chunk_work)``)
-      and decode slots throttle prefill throughput — the interference
-      disaggregation removes,
-    * **disaggregated** — one PREFILL device + one DECODE device over a
-      shared far tier.  The prefill device runs prompts back-to-back at
-      full compute density (no decode interference) and emits each
-      request's **first token itself** (the engine's PREFILL role
-      finishes at first token), then graduates: a BULK astore parks the
-      prompt's KV pages + aux residue in the shared tier
-      (``base_latency + pages * page_bytes / bandwidth``, overlapped
-      with the next prompt's compute).  The decode device admits each
-      handoff through a LATENCY fetch of those pages — overlapped with
-      its running decode batch, exactly like the engine's resume
-      machinery — and decodes the remaining tokens at an *unstretched*
-      ``t_decode_step`` (no chunk work in its steps).
-
-    The trade this exposes is the one production disaggregation is
-    deployed for: the fused engines win raw TTFT and aggregate
-    throughput at these scales (chunking already hides prefill FLOPs
-    under decode weight streaming, and two fused devices prefill two
-    prompt streams in parallel), while the disaggregated split wins
-    **inter-token latency** — the decode device's steps are never
-    stretched by chunk work, so TPOT is flat ``t_decode_step`` instead
-    of ``max(t_decode_step, chunk_work)`` whenever prompts are in
-    flight.  Returns mean TTFT, mean TPOT and aggregate decode
-    tokens/s for both sides; ``ttft_ratio`` / ``tpot_ratio`` /
-    ``goodput_ratio`` are oriented so > 1 always means disaggregation
-    won that axis.
-    """
-    per_dev = max(1, int(round(oversubscription * max_batch * 4)))
-    n_seqs = 2 * per_dev
-    pages = -(-prompt_tokens // page_size)
-    xfer = base_latency + pages * page_bytes / bandwidth
-
-    # -- fused baseline: one engine's mixed-batching loop, half traffic
-    # (the second device is identical and independent)
-    fused = simulate_mixed_batching(
-        oversubscription, max_batch=max_batch,
-        prompt_tokens=prompt_tokens, new_tokens=new_tokens,
-        page_size=page_size, chunk_tokens=chunk_tokens,
-        chunk_slots=chunk_slots, low_watermark=low_watermark,
-        t_decode_step=t_decode_step, t_prefill_token=t_prefill_token)
-    fused_ttft = fused["ttft_mixed_us"] * 1e-6
-    fused_tpot = fused["tpot_mixed_us"] * 1e-6
-    fused_tok_s = 2 * fused["tok_per_s_mixed"]        # two devices
-
-    # -- disaggregated: prefill device serialises every prompt ---------
-    # (dense full-compute prefill + the first-token step; graduation's
-    # BULK park overlaps the next prompt)
-    now = 0.0
-    ttft = []
-    ready = []                   # handoff visible to the decode side at
-    for _ in range(n_seqs):      # first-token time + BULK park
-        now += prompt_tokens * t_prefill_token + t_decode_step
-        ttft.append(now)
-        ready.append(now + xfer)
-
-    # -- decode device: admit handoffs through a LATENCY fetch that
-    # overlaps the running batch, then pure decode steps
-    t = 0.0
-    remaining = {i: new_tokens - 1 for i in range(n_seqs)}
-    running: Dict[int, int] = {}
-    nxt = 0
-    decoded = 0
-    while remaining or running:
-        while nxt < n_seqs and len(running) < max_batch:
-            # fetch overlaps decode: the admission lands at whichever is
-            # later of "pages arrived" and "a step boundary passed"
-            at = ready[nxt] + xfer
-            if at > t and running:
-                break            # keep decoding; admit once it lands
-            t = max(t, at)
-            running[nxt] = remaining.pop(nxt)
-            nxt += 1
-        if not running:
-            if nxt < n_seqs:
-                t = max(t, ready[nxt] + xfer)
-                continue
-            break
-        t += t_decode_step       # one unstretched decode step, all slots
-        decoded += len(running)
-        for seq in sorted(running):
-            running[seq] -= 1
-            if running[seq] <= 0:
-                del running[seq]
-    disagg_ttft = sum(ttft) / n_seqs
-    # aggregate completion: first tokens on the prefill device, the rest
-    # on the decode device; the decode device finishes last
-    wall = max(t, ttft[-1])
-    disagg_tok_s = n_seqs * new_tokens / wall
-
-    return {
-        "oversubscription": oversubscription,
-        "n_seqs": n_seqs,
-        "handoff_xfer_us": xfer * 1e6,
-        "ttft_fused_us": fused_ttft * 1e6,
-        "ttft_disagg_us": disagg_ttft * 1e6,
-        "ttft_ratio": fused_ttft / disagg_ttft,
-        # the decode device's steps are never stretched by chunk work
-        "tpot_fused_us": fused_tpot * 1e6,
-        "tpot_disagg_us": t_decode_step * 1e6,
-        "tpot_ratio": fused_tpot / t_decode_step,
-        "tok_per_s_fused": fused_tok_s,
-        "tok_per_s_disagg": disagg_tok_s,
-        "goodput_ratio": disagg_tok_s / fused_tok_s,
-    }
-
-
-def simulate_prefix_reuse(
-    shared_frac: float,
-    *,
-    oversubscription: float = 2.0,
-    max_batch: int = 4,
-    prefix_tokens: int = 240,
-    tail_tokens: int = 16,
-    new_tokens: int = 32,
-    page_size: int = 16,
-    chunk_tokens: int = 16,
-    chunk_slots: int = 2,
-    low_watermark: int = 1,
-    t_decode_step: float = 20e-6,
-    t_prefill_token: float = 2.5e-6,
-    t_page_fetch: float = 15e-6,
-) -> Dict[str, float]:
-    """Cross-request prefix sharing vs recompute-everything, deterministic.
-
-    Models system-prompt traffic at ``oversubscription`` x request load:
-    ``shared_frac`` of the burst's requests carry an identical
-    ``prefix_tokens``-long prefix ahead of a unique tail (defaults: a
-    240-token system prompt over a 16-token user turn — the
-    thousands-of-users-one-template regime prefix caching targets,
-    with prompt chunks compute-dense next to the memory-bound decode
-    step).  Both engines
-    are the chunk-queue engine of :func:`simulate_mixed_batching`; the
-    *sharing* engine additionally runs the
-    :mod:`repro.paging.prefix_cache` policy:
-
-    * the first shared request to finish its prefix chunks *interns*
-      the full prefix pages,
-    * every later shared request maps those pages instead of computing
-      them — only the boundary page (the hash covers full pages and the
-      first token must still produce logits) and the unique tail pay
-      prefill FLOPs,
-    * under pool pressure the interned pages are evicted to the far
-      tier (clean, for free — the intern writes the far home); a hit
-      then pays one overlapped LATENCY page-fetch round
-      (``t_page_fetch``, all pages under one window) before its first
-      chunk instead of the chunks themselves.
-
-    This is the serving-level aggregation claim of the follow-up AMU
-    paper (2404.11044): far memory plus massive outstanding aloads
-    turns recomputation into cheap overlappable transfers.  Returns
-    mean/p95 TTFT for both engines, the TTFT speedup, and the fraction
-    of prefill FLOPs the sharing engine skipped.
-    """
-    n_seqs = max(1, int(round(oversubscription * max_batch * 4)))
-    n_shared = int(round(shared_frac * n_seqs))
-    prompt_tokens = prefix_tokens + tail_tokens
-    pages_per_seq = -(-(prompt_tokens + new_tokens) // page_size)
-    pool_pages = max_batch * pages_per_seq + low_watermark
-    # full pages only, and the last prompt token always recomputes
-    hit_tokens = min(((prefix_tokens - 1) // page_size) * page_size,
-                     ((prompt_tokens - 1) // page_size) * page_size)
-    hit_pages = hit_tokens // page_size
-
-    def run(sharing: bool) -> Dict[str, float]:
-        now = 0.0
-        free_pages = pool_pages
-        queue = list(range(n_seqs))          # seq < n_shared: shared prefix
-        running: Dict[int, int] = {}         # seq -> decoded tokens
-        prefilling: Dict[int, int] = {}      # seq -> prefilled tokens
-        ready_at: Dict[int, float] = {}      # far-hit fetch completion time
-        held: Dict[int, int] = {}
-        ttft = [0.0] * n_seqs
-        done = 0
-        interned = False
-        prefill_tokens_done = 0
-        far_hit_admissions = 0
-        while done < n_seqs:
-            while queue and (len(running) + len(prefilling)) < max_batch:
-                need = -(-prompt_tokens // page_size)
-                if free_pages - need < low_watermark:
-                    break
-                seq = queue.pop(0)
-                shared = seq < n_shared
-                start = 0
-                if sharing and shared and interned and hit_pages:
-                    start = hit_tokens
-                    # interned pages resident only while the pool has
-                    # slack; at real oversubscription they live in the
-                    # far tier and the hit pays one overlapped fetch
-                    if free_pages - need < hit_pages + low_watermark:
-                        ready_at[seq] = now + t_page_fetch
-                        far_hit_admissions += 1
-                free_pages -= need
-                held[seq] = need
-                prefilling[seq] = start
-            if not running and not prefilling:
-                break
-            chunk_work = 0
-            for seq in sorted(prefilling)[:chunk_slots]:
-                if ready_at.get(seq, 0.0) > now:
-                    continue                 # pages still arriving
-                take = min(chunk_tokens, prompt_tokens - prefilling[seq])
-                prefilling[seq] += take
-                chunk_work += take
-            step = max(t_decode_step if running else 0.0,
-                       chunk_work * t_prefill_token)
-            step = step or t_decode_step
-            now += step
-            prefill_tokens_done += chunk_work
-            for seq in sorted(prefilling):
-                if prefilling[seq] >= prompt_tokens:
-                    del prefilling[seq]
-                    ready_at.pop(seq, None)
-                    ttft[seq] = now
-                    running[seq] = 1
-                    if seq < n_shared:
-                        interned = True
-            for seq in sorted(running):
-                need = (-(-(prompt_tokens + running[seq] + 1) // page_size)
-                        - held[seq])
-                if need > 0 and free_pages >= need:
-                    free_pages -= need
-                    held[seq] += need
-                running[seq] += 1
-                if running[seq] >= new_tokens:
-                    free_pages += held.pop(seq)
-                    del running[seq]
-                    done += 1
-        ttft_sorted = sorted(ttft)
-        return {
-            "ttft_mean": sum(ttft) / n_seqs,
-            "ttft_p95": ttft_sorted[min(n_seqs - 1, int(0.95 * n_seqs))],
-            "wall": now,
-            "prefill_tokens": prefill_tokens_done,
-            "far_hits": far_hit_admissions,
-        }
-
-    plain = run(sharing=False)
-    shared = run(sharing=True)
-    return {
-        "shared_frac": shared_frac,
-        "oversubscription": oversubscription,
-        "hit_tokens": hit_tokens,
-        "ttft_plain_us": plain["ttft_mean"] * 1e6,
-        "ttft_shared_us": shared["ttft_mean"] * 1e6,
-        "ttft_p95_plain_us": plain["ttft_p95"] * 1e6,
-        "ttft_p95_shared_us": shared["ttft_p95"] * 1e6,
-        "ttft_speedup": plain["ttft_mean"] / max(shared["ttft_mean"], 1e-30),
-        "prefill_tokens_plain": plain["prefill_tokens"],
-        "prefill_tokens_shared": shared["prefill_tokens"],
-        "prefill_flops_saved_frac": (
-            1.0 - shared["prefill_tokens"] / max(1, plain["prefill_tokens"])),
-        "far_hits": shared["far_hits"],
-        "wall_speedup": plain["wall"] / max(shared["wall"], 1e-30),
     }
 
 

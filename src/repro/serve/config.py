@@ -12,9 +12,7 @@ with grouped sub-configs —
 
 — and the machinery that keeps every consumer in lockstep with it:
 
-  * ``Engine(cfg, params, EngineConfig(...))`` is the construction path;
-    the old flat kwargs are accepted for one release through
-    :func:`engine_config_from_kwargs` (DeprecationWarning + translate),
+  * ``Engine(cfg, params, EngineConfig(...))`` is the construction path,
   * ``launch/serve`` *auto-generates* its ``--`` flags from these
     dataclass fields (:func:`add_config_args` /
     :func:`config_from_args`), so the CLI cannot drift from the API,
@@ -40,7 +38,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import enum
-import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Tuple
 
@@ -49,8 +46,7 @@ from repro.paging import WatermarkPolicy
 __all__ = [
     "Tier", "EngineRole", "VirtualClock", "PagingConfig",
     "ChunkingConfig", "SchedulerConfig", "SpeculationConfig", "ObsConfig",
-    "EngineConfig",
-    "engine_config_from_kwargs", "add_config_args", "config_from_args",
+    "EngineConfig", "add_config_args", "config_from_args",
 ]
 
 
@@ -274,60 +270,6 @@ class EngineConfig:
         default_factory=SpeculationConfig, metadata={"cli": True})
     obs: ObsConfig = field(default_factory=ObsConfig,
                            metadata={"cli": True})
-
-
-# -- legacy flat-kwarg shim ---------------------------------------------------
-
-#: old Engine.__init__ kwarg -> (sub-config attr | None, field name)
-_LEGACY_MAP = {
-    "max_batch": (None, "max_batch"),
-    "max_len": (None, "max_len"),
-    "prefill_buckets": (None, "prefill_buckets"),
-    "greedy": (None, "greedy"),
-    "mesh": (None, "mesh"),
-    "kernel_impl": (None, "kernel_impl"),
-    "paging": ("paging", "enabled"),
-    "page_size": ("paging", "page_size"),
-    "device_pages": ("paging", "device_pages"),
-    "hot_tail_pages": ("paging", "hot_tail_pages"),
-    "offload_finished": ("paging", "offload_finished"),
-    "watermark": ("paging", "watermark"),
-    "pager_factory": ("paging", "pager_factory"),
-    "chunk_tokens": ("chunking", "chunk_tokens"),
-    "chunk_slots": ("chunking", "chunk_slots"),
-    "prefix_cache": ("chunking", "prefix_cache"),
-    "step_dt": ("scheduler", "step_dt"),
-    "clock": ("scheduler", "clock"),
-}
-
-
-def engine_config_from_kwargs(base: Optional[EngineConfig] = None,
-                              **kwargs) -> EngineConfig:
-    """Translate the pre-EngineConfig flat kwargs (one DeprecationWarning
-    per construction); unknown names raise TypeError like any bad kwarg."""
-    unknown = set(kwargs) - set(_LEGACY_MAP)
-    if unknown:
-        raise TypeError(
-            f"Engine() got unexpected keyword arguments {sorted(unknown)}; "
-            "see repro.serve.config.EngineConfig for the supported fields")
-    warnings.warn(
-        "flat Engine(**kwargs) construction is deprecated; build an "
-        "EngineConfig (repro.serve.config) instead: "
-        "Engine(cfg, params, EngineConfig(...))",
-        DeprecationWarning, stacklevel=3)
-    cfg = base or EngineConfig()
-    top: dict = {}
-    subs: dict = {"paging": {}, "chunking": {}, "scheduler": {}}
-    for name, value in kwargs.items():
-        group, fname = _LEGACY_MAP[name]
-        if group is None:
-            top[fname] = value
-        else:
-            subs[group][fname] = value
-    for group, vals in subs.items():
-        if vals:
-            top[group] = dataclasses.replace(getattr(cfg, group), **vals)
-    return dataclasses.replace(cfg, **top)
 
 
 # -- CLI auto-generation ------------------------------------------------------

@@ -128,8 +128,7 @@ from repro.paging import (DeadlineQueue, EventKind, EventLoop, PagePool,
                           PageState, PageTable, Pager, PagingError,
                           PrefixCache, WatermarkPolicy, pages_for)
 from repro.serve.admission import AdmissionMixin
-from repro.serve.config import (EngineConfig, EngineRole, Tier,
-                                VirtualClock, engine_config_from_kwargs)
+from repro.serve.config import EngineConfig, EngineRole, Tier, VirtualClock
 from repro.serve.decode import DecodeMixin
 from repro.serve.disagg import HandoffBoard
 from repro.serve.kv_cache import SlotPool
@@ -157,8 +156,7 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
         outputs = eng.run()                           # {rid: tokens}
 
     Construction takes one frozen :class:`~repro.serve.config.
-    EngineConfig` (the documented path; the pre-config flat kwargs are
-    still accepted for one release with a DeprecationWarning).  Knobs:
+    EngineConfig`.  Knobs:
     ``paging.device_pages`` below ``max_batch * pages_per_seq``
     oversubscribes the pool (watermark admission + preemption, §2.3.2);
     ``chunking.chunk_tokens`` switches admission to the chunk queue
@@ -184,10 +182,7 @@ class Engine(AdmissionMixin, TransferMixin, DecodeMixin):
         cfg: ModelConfig,
         params,
         config: Optional[EngineConfig] = None,
-        **legacy_kwargs,
     ):
-        if legacy_kwargs:
-            config = engine_config_from_kwargs(config, **legacy_kwargs)
         ec = config or EngineConfig()
         if ops.on_tpu() and ec.kernel_impl in ("interpret", "xla"):
             # on a TPU the serving path runs the compiled Pallas kernels;

@@ -24,8 +24,8 @@ the uniform back-to-back submissions our tests generate:
 
 :func:`generate` returns a list of :class:`WorkloadRequest` sorted by
 arrival time, deterministically from a seed — the same trace feeds the
-engine, the ``simulate_slo_schedule`` virtual-clock model, and the
-benchmark sweep, so their numbers are comparable.
+engine and the ``simulate_slo_schedule`` virtual-clock model, so their
+numbers are comparable.
 """
 
 from __future__ import annotations

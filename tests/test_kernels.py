@@ -139,7 +139,7 @@ def test_ssd(B, T, H, P, N, chunk):
 
 def test_kernels_match_model_chunked_forms():
     """Pallas kernels agree with the models' XLA chunked forms (the
-    exact functions the dry-run lowers)."""
+    exact functions the XLA path lowers)."""
     from repro.models.ssm import ssd_chunked, wkv6_chunked
     B, T, H, K = 1, 128, 2, 32
     r, k, v = _rand((B, T, H, K)), _rand((B, T, H, K)), _rand((B, T, H, K))

@@ -11,9 +11,9 @@ The scheduler redesign's acceptance surface (PR 6):
   * batch-tier requests are shed first under pressure but never starve:
     every admitted request completes,
   * under overload the SLO policy's interactive goodput must dominate
-    watermark-FIFO's (the ``slo_goodput_sweep`` acceptance row),
-  * the frozen ``EngineConfig`` path and the deprecated flat-kwarg shim
-    build identical engines; unknown kwargs still raise ``TypeError``.
+    watermark-FIFO's,
+  * an ``EngineConfig`` is the one way to configure an ``Engine``: flat
+    or unknown keywords raise ``TypeError``.
 """
 
 import warnings
@@ -181,33 +181,26 @@ def test_slo_beats_watermark_on_engine_trace(setup):
     assert slo["interactive"]["attainment"] >= wm["interactive"]["attainment"]
 
 
-def test_engine_config_and_legacy_shim_agree(setup):
-    """Flat kwargs still construct (one DeprecationWarning) and behave
-    exactly like the EngineConfig path; unknown kwargs raise."""
+def test_engine_takes_only_engine_config(setup):
+    """An EngineConfig is the one way to configure an Engine: an unknown
+    keyword and a flat field keyword (``max_batch=2``) both raise
+    TypeError, and the config path serves without a warning."""
     cfg, params, _ = setup
-    prompts = [np.arange(6) % cfg.vocab_size, np.arange(9) % cfg.vocab_size]
-
-    def drive(eng):
-        for p in prompts:
-            eng.submit(p, max_new_tokens=4)
-        return eng.run()
-
-    new = drive(Engine(cfg, params, EngineConfig(
-        max_batch=2, max_len=64, prefill_buckets=(16,),
-        paging=PagingConfig(page_size=8, device_pages=6))))
-    with pytest.warns(DeprecationWarning):
-        old = drive(Engine(cfg, params, max_batch=2, max_len=64,
-                           prefill_buckets=(16,), page_size=8,
-                           device_pages=6))
-    assert old == new
 
     with pytest.raises(TypeError, match="no_such_knob"):
         Engine(cfg, params, no_such_knob=1)
+    with pytest.raises(TypeError, match="max_batch"):
+        Engine(cfg, params, max_batch=2)
 
+    prompts = [np.arange(6) % cfg.vocab_size, np.arange(9) % cfg.vocab_size]
     with warnings.catch_warnings():
         warnings.simplefilter("error")       # config path must not warn
-        Engine(cfg, params, EngineConfig(max_batch=2, max_len=64,
-                                         prefill_buckets=(16,)))
+        eng = Engine(cfg, params, EngineConfig(
+            max_batch=2, max_len=64, prefill_buckets=(16,),
+            paging=PagingConfig(page_size=8, device_pages=6)))
+        rids = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        out = eng.run()
+    assert [len(out[r]) for r in rids] == [4, 4]
 
 
 def test_one_clock_stamps_every_timestamp(setup):
